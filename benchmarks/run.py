@@ -18,6 +18,8 @@ import importlib
 import sys
 import traceback
 
+from repro import compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -67,4 +69,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -203,6 +203,7 @@ def overlap_model(m: int = 7, meshes=((2, 4), (2, 16), (2, 32))) -> None:
     pr, pc = meshes[-1]
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={pr}"
+    env["JAX_PLATFORMS"] = "cpu"       # fake devices, never the chip
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     out = subprocess.run(

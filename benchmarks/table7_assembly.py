@@ -12,7 +12,7 @@ host flops the device path sheds.
 Timed on the real implementations at CPU scale:
 
 * ``t7.device_assemble``         jitted fields -> assembled payload
-  (vmapped quadrature + cached COO scatter)
+  (element blocks + cached COO scatter)
 * ``t7.device_update_recompute`` the fused hot loop: fields -> hierarchy
   (``gamg.make_coeff_recompute``) — ONE traced program, zero host bytes
 * ``t7.host_assemble``           the numpy golden loop (per-element Ke)

@@ -17,10 +17,11 @@ import numpy as np
 import repro.core  # noqa: F401  (enables fp64)
 from repro.core import gamg
 from repro.fem.assemble import assemble_elasticity, inclusion_fields
+from repro import compile_cache
 
 
 def main(m: int = 7) -> None:
-    print(f"assembling {m}^3 Q1 elasticity on device (vmapped quadrature)")
+    print(f"assembling {m}^3 Q1 elasticity on device")
     prob = assemble_elasticity(m)                  # path="device" default
     ne = prob.mesh.n_elements
     print(f"  n = {prob.n} unknowns, {ne} elements, coefficient update "
@@ -50,4 +51,5 @@ def main(m: int = 7) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(int(sys.argv[1]) if len(sys.argv) > 1 else 7)

@@ -21,6 +21,7 @@ import numpy as np
 import repro.core  # noqa: F401  (enables fp64)
 from repro.fem.assemble import assemble_elasticity
 from repro.sim import MarchConfig, SofteningScenario, StalenessConfig, march
+from repro import compile_cache
 
 
 def main(m: int = 5, n_steps: int = 8) -> None:
@@ -56,4 +57,5 @@ def main(m: int = 5, n_steps: int = 8) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(*(int(a) for a in sys.argv[1:3]))

@@ -26,6 +26,7 @@ from repro.core import gamg
 from repro.fem.assemble import assemble_elasticity
 from repro.multirhs import AMGSolveServer
 from repro.obs import MetricsRegistry, describe_tally, use
+from repro import compile_cache
 
 
 def main(m: int = 6) -> None:
@@ -100,4 +101,5 @@ def main(m: int = 6) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(int(sys.argv[1]) if len(sys.argv) > 1 else 6)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import repro.core  # noqa: F401  (enables fp64)
 from repro.core import gamg
 from repro.fem.assemble import assemble_elasticity
+from repro import compile_cache
 
 
 def main(m: int = 9) -> None:
@@ -49,4 +50,5 @@ def main(m: int = 9) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(int(sys.argv[1]) if len(sys.argv) > 1 else 9)

@@ -19,6 +19,7 @@ import repro.core  # noqa: F401  (enables fp64)
 from repro.core import gamg
 from repro.fem.assemble import assemble_elasticity
 from repro.multirhs import AMGSolveServer
+from repro import compile_cache
 
 
 def main(m: int = 7) -> None:
@@ -61,4 +62,5 @@ def main(m: int = 7) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(int(sys.argv[1]) if len(sys.argv) > 1 else 7)

@@ -25,7 +25,7 @@ class ElasticityConfig:
     rtol: float = 1e-8           # unpreconditioned residual norm
     maxiter: int = 200
     reuse_interpolation: bool = True   # -pc_gamg_reuse_interpolation
-    # assembly path: "device" (JAX vmapped quadrature + DeviceAssembler —
+    # assembly path: "device" (JAX element blocks + DeviceAssembler —
     # enables the jitted update_coefficients hot loop) or "host" (numpy
     # golden reference)
     assembly: str = "device"

@@ -28,7 +28,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import jit_args
 from repro.core.block_csr import BlockCSR, coo_to_csr_structure
+from repro.core.lanes import from_lanes, gather_lanes, segment_sum_lanes, \
+    to_lanes
 
 Array = jax.Array
 
@@ -96,34 +99,42 @@ def set_values_coo(plan: BlockCOOPlan, values: Array, *,
 
     ``values``: (n_input, br, bc) dense blocks, one per declared coordinate,
     in declaration order — exactly PETSc's MatSetValuesCOO value stream.
-    ``use_kernel``/``interpret`` default per backend (Pallas streaming
-    segment-sum on TPU, jnp ``segment_sum`` elsewhere).
+    The XLA ``segment_sum`` runs by default on every backend: the Pallas
+    streaming segment-sum (``use_kernel=True``) needs an in-kernel cumsum,
+    which Mosaic does not lower, so it runs in interpret mode only.
     """
-    from repro.kernels import backend as _backend
     expected = (plan.n_input, plan.br, plan.bc)
     if values.shape != expected:
         raise ValueError(f"value stream shape {values.shape} != {expected} "
                          f"(one ({plan.br}, {plan.bc}) block per declared "
                          f"coordinate, in declaration order)")
-    vals = values[jnp.asarray(plan.keep)][jnp.asarray(plan.order)]
-    seg = jnp.asarray(plan.out_idx_sorted)
-    if _backend.resolve_use_kernel(use_kernel):
+    if use_kernel:
         from repro.kernels.block_seg_sum import ops as _k
-        data = _k.block_seg_sum(
-            vals, seg, plan.nnzb,
-            interpret=_backend.resolve_interpret(interpret))
+        vals = values[jnp.asarray(plan.keep[plan.order])]
+        data = _k.block_seg_sum(vals, jnp.asarray(plan.out_idx_sorted),
+                                plan.nnzb, interpret=interpret)
     else:
-        data = jax.ops.segment_sum(vals, seg, num_segments=plan.nnzb,
-                                   indices_are_sorted=True)
+        data = _program(plan)(values)
     return BlockCSR.from_arrays(plan.indptr, plan.indices, data, plan.nbc)
 
 
+def _program(plan: BlockCOOPlan) -> jit_args.Program:
+    """``set_values_coo_data`` compiled once per plan, the plan's arrays
+    passed as arguments."""
+    prog = plan.__dict__.get("_set_values")
+    if prog is None:
+        prog = plan.__dict__["_set_values"] = jit_args.Program(
+            set_values_coo_data, plan)
+    return prog
+
+
 def set_values_coo_data(plan: BlockCOOPlan, values: Array) -> Array:
-    """Numeric phase returning only the data array (for jitted pipelines)."""
-    vals = values[jnp.asarray(plan.keep)][jnp.asarray(plan.order)]
-    return jax.ops.segment_sum(vals, jnp.asarray(plan.out_idx_sorted),
-                               num_segments=plan.nnzb,
-                               indices_are_sorted=True)
+    """Numeric phase returning only the data array (for jitted pipelines):
+    the kept blocks in sorted order, summed into their output slots, with
+    the block count on the lanes (``repro.core.lanes``)."""
+    t = gather_lanes(to_lanes(values), plan.keep[plan.order])
+    out = segment_sum_lanes(t, plan.out_idx_sorted, plan.nnzb)
+    return from_lanes(out, (plan.br, plan.bc))
 
 
 def scalar_coo_plan_bytes(plan: BlockCOOPlan) -> int:

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.lanes import gather_lanes, to_lanes
+
 Array = jax.Array
 
 _STATE_COUNTER = [0]
@@ -134,8 +136,10 @@ class BlockCSR:
         sel[for_r, within] = np.arange(self.nnzb)
         mask = sel >= 0
         gather = np.where(mask, sel, 0)
+        from repro.kernels.tiling import col_windows
         return ELLPlan(indices=idx, gather=gather, mask=mask, nbc=self.nbc,
-                       state_token=self.state_token)
+                       state_token=self.state_token,
+                       windows=col_windows(idx))
 
     def to_ell(self, pad_to: int | None = None) -> "BlockELL":
         """Convert to padded ELL layout for the SpMV kernels."""
@@ -148,7 +152,7 @@ class BlockCSR:
         Paper Sec. 3.2: operator inspection runs over the bs x bs blocks of
         the block storage directly (no scalar expansion).
         """
-        return jnp.sqrt(jnp.sum(self.data * self.data, axis=(1, 2)))
+        return _block_norms(self.data)
 
     def diagonal_blocks(self) -> Array:
         """(nbr, br, bc) array of diagonal blocks (zero where absent)."""
@@ -183,18 +187,25 @@ class ELLPlan:
     mask: np.ndarray      # (nbr, kmax) bool
     nbc: int
     state_token: int
+    windows: np.ndarray = None  # x-window plan of the ELL kernels
 
     def ell_data(self, data: Array) -> Array:
-        """Numeric phase: scatter BCSR values into the ELL layout (device)."""
-        return data[jnp.asarray(self.gather)] * jnp.asarray(
-            self.mask, data.dtype)[..., None, None]
+        """Numeric phase: scatter BCSR values into the ELL layout (device).
+
+        Gathered lane-dense (``repro.core.lanes``), then viewed as
+        ``(nbr, kmax, br, bc)`` — the order the TPU stores that shape in."""
+        t = gather_lanes(to_lanes(data), self.gather.T) * jnp.asarray(
+            self.mask.T, data.dtype)
+        return jnp.transpose(t.reshape(data.shape[1:] + self.gather.T.shape),
+                             (3, 2, 0, 1))
 
     def build(self, data: Array) -> "BlockELL":
         return BlockELL(indices=jnp.asarray(self.indices),
                         data=self.ell_data(data),
                         mask=jnp.asarray(self.mask),
                         nbc=self.nbc,
-                        state_token=self.state_token)
+                        state_token=self.state_token,
+                        windows=jnp.asarray(self.windows))
 
 
 class _HashableArray:
@@ -223,6 +234,10 @@ class BlockELL:
     mask: Array      # (nbr, kmax) bool
     nbc: int
     state_token: int = 0
+    # (ceil(nbr/128), n_win) int32: the 128-column tiles of x each 128-row
+    # tile reads (``repro.kernels.tiling.col_windows``) — the plan of the
+    # kernels' in-kernel x gather; None = derived from concrete indices
+    windows: Array = None
 
     @property
     def nbr(self) -> int:
@@ -254,16 +269,18 @@ class BlockELL:
             return self
         return BlockELL(indices=self.indices,
                         data=self.data.astype(dtype), mask=self.mask,
-                        nbc=self.nbc, state_token=self.state_token)
+                        nbc=self.nbc, state_token=self.state_token,
+                        windows=self.windows)
 
     def tree_flatten(self):
-        return (self.indices, self.data, self.mask), (self.nbc,
-                                                      self.state_token)
+        return (self.indices, self.data, self.mask, self.windows), (
+            self.nbc, self.state_token)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         nbc, tok = aux
-        return cls(children[0], children[1], children[2], nbc, tok)
+        return cls(children[0], children[1], children[2], nbc, tok,
+                   children[3])
 
 
 # ---------------------------------------------------------------------------
@@ -392,6 +409,22 @@ def transpose_apply_plan(A: BlockCSR, kmax: int) -> EllTransposePlan:
     return EllTransposePlan(rows=jnp.asarray(rows),
                             gather=jnp.asarray(gather),
                             mask=jnp.asarray(mask), nbr=A.nbr)
+
+
+@jax.jit
+def _block_norms(data: Array) -> Array:
+    return jnp.sqrt(jnp.sum(data * data, axis=(1, 2)))
+
+
+def structure_bcsr(indptr, indices, nbc: int, br: int, bc: int, dtype,
+                   state_token: int = 0) -> BlockCSR:
+    """A ``BlockCSR`` standing for a structure only (symbolic phases): its
+    data is a zero-stride host array of the right shape, so nothing is
+    allocated or computed on the device."""
+    data = np.broadcast_to(np.zeros((), dtype), (len(indices), br, bc))
+    return BlockCSR(np.asarray(indptr, np.int64),
+                    np.asarray(indices, np.int32), data, int(nbc),
+                    state_token=state_token)
 
 
 @partial(jax.jit, static_argnames=("nbr", "br", "bc"))

@@ -41,6 +41,8 @@ from repro.core.block_csr import (
     transpose_apply_plan,
     transpose_bcsr,
 )
+from repro.core import jit_args
+from repro.core.lanes import block_matmul_lanes
 from repro.core.ptap import PtAPCache, ptap_numeric_data, ptap_symbolic
 from repro.core.smooth import (
     invert_diag_blocks,
@@ -180,11 +182,14 @@ def setup(A: BlockCSR, B: Array, *, theta: float = 0.08,
         Ptent, Bc = tentative_prolongator(aggr, Bcur, bs)
         P, omega, lam, _plans = smoothed_prolongator(Acur, Ptent)
         cache = ptap_symbolic(Acur, P)
-        a_next_data = ptap_numeric_data(cache, Acur.data, P.data)
+        # each numeric phase is one program with its plan as arguments
+        # (a TPU compiles every eager operation on its own)
+        a_next_data = jit_args.call(ptap_numeric_data, cache, Acur.data,
+                                    P.data)
         Anext = BlockCSR.from_arrays(cache.ac_plan.indptr,
                                      cache.ac_plan.indices, a_next_data,
                                      cache.n_coarse)
-        p_ell = P.to_ell()
+        p_ell = jit_args.call(ELLPlan.build, P.ell_plan(), P.data)
         if restriction == "stored":
             R = transpose_bcsr(P)
             r_ell, pt = R.to_ell(), None
@@ -253,9 +258,11 @@ def level_state(ls: LevelSetup, a_data: Array,
     dinv = invert_diag_blocks(
         diag.astype(policy.factor_dtype)).astype(h)
     a_ell = ls.a_ell_plan.build(a_data)
-    dinva_ell = jnp.einsum("nab,nkbc->nkac", dinv.astype(acc),
-                           a_ell.data.astype(acc),
-                           preferred_element_type=acc).astype(h)
+    # D^{-1} A row scaling on lane-dense blocks (rows minor)
+    dinva_ell = jnp.transpose(block_matmul_lanes(
+        jnp.transpose(dinv.astype(acc), (1, 2, 0)),
+        jnp.transpose(a_ell.data.astype(acc), (2, 3, 1, 0))),
+        (3, 2, 0, 1)).astype(h)
     lam = lambda_max_dinv_a(a_ell.indices, dinva_ell, a_ell.mask,
                             A.nbr, A.br)
     r_ell = ls.r_ell.astype(h) if ls.r_ell is not None else None
@@ -352,21 +359,22 @@ def recompute(setupd: GAMGSetup, a_fine_data: Array) -> Hierarchy:
 
 
 def make_recompute(setupd: GAMGSetup):
-    """Jitted hot-recompute closure (symbolic data baked in as constants)."""
-    return jax.jit(partial(recompute, setupd))
+    """Jitted hot-recompute program ``a_fine_data -> Hierarchy``.
+
+    The setup's plans and fixed prolongator payloads are arguments of the
+    program (``repro.core.jit_args``), not constants baked into it, so the
+    executable stays small enough for the persistent compilation cache.
+    One per setup: every holder of the setup (``GAMGSolver``,
+    ``AMGSolveServer``, the recovery ladder) shares the one compiled
+    program instead of compiling its own copy."""
+    fn = setupd.__dict__.get("_recompute_jit")
+    if fn is None:
+        fn = setupd.__dict__["_recompute_jit"] = jit_args.Program(
+            recompute, setupd)
+    return fn
 
 
-def make_coeff_recompute(setupd: GAMGSetup, assembler):
-    """Jitted coefficient hot path: ``(E, nu) -> Hierarchy``.
-
-    Fuses device FEM assembly (vmapped quadrature -> cached blocked-COO
-    scatter, ``repro.fem.device_stiffness.DeviceAssembler.coo_data``) with
-    the state-gated PtAP recompute into ONE traced program — the whole
-    ``update -> set_values_coo -> recompute`` step of the quasi-static hot
-    loop runs device-resident with zero host transfers.  The assembler's
-    plan and the setup's symbolic data are baked in as constants; the
-    program retraces only if those structures change.
-    """
+def _check_assembler(setupd: GAMGSetup, assembler) -> None:
     nnzb = setupd.levels[0].A0.nnzb if setupd.levels \
         else setupd.coarse_struct.nnzb
     if assembler.plan.nnzb != nnzb:
@@ -377,10 +385,26 @@ def make_coeff_recompute(setupd: GAMGSetup, assembler):
             f"plan has {assembler.plan.nnzb} output blocks, the fine "
             f"level has {nnzb}")
 
-    def coeff_recompute(E, nu):
-        return recompute(setupd, assembler.coo_data(E, nu))
 
-    return jax.jit(coeff_recompute)
+def _coeff_recompute(objs, E, nu):
+    setupd, assembler = objs
+    return recompute(setupd, assembler.coo_data(E, nu))
+
+
+def make_coeff_recompute(setupd: GAMGSetup, assembler):
+    """Jitted coefficient hot path: ``(E, nu) -> Hierarchy``.
+
+    Fuses device FEM assembly (element blocks -> cached blocked-COO
+    scatter, ``repro.fem.device_stiffness.DeviceAssembler.coo_data``) with
+    the state-gated PtAP recompute into ONE program — the whole
+    ``update -> set_values_coo -> recompute`` step of the quasi-static hot
+    loop runs device-resident with zero host transfers.  The assembler's
+    plan and the setup's symbolic data are program arguments
+    (``repro.core.jit_args``); the program retraces only if those
+    structures change.
+    """
+    _check_assembler(setupd, assembler)
+    return jit_args.Program(_coeff_recompute, (setupd, assembler))
 
 
 def hier_solve(setupd: GAMGSetup, hier: Hierarchy, b: Array,
@@ -476,13 +500,7 @@ def make_coeff_solve(setupd: GAMGSetup, assembler, rtol: float = 1e-8,
     The fully-fused scan/while segments (scenario law + staleness
     monitor riding along) live in ``repro.sim.driver``.
     """
-    nnzb = setupd.levels[0].A0.nnzb if setupd.levels \
-        else setupd.coarse_struct.nnzb
-    if assembler.plan.nnzb != nnzb:
-        raise ValueError(
-            f"assembler plan does not match the setup's fine operator: "
-            f"plan has {assembler.plan.nnzb} output blocks, the fine "
-            f"level has {nnzb}")
+    _check_assembler(setupd, assembler)
 
     def coeff_solve(E, nu, b, x0):
         hier = recompute(setupd, assembler.coo_data(E, nu))

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.block_csr import BlockCSR, transpose_structure
+from repro.core.block_csr import BlockCSR, structure_bcsr, \
+    transpose_structure
 from repro.core.spgemm import (
     SpGEMMPlan,
     spgemm_numeric_data,
@@ -68,14 +69,11 @@ def ptap_symbolic(A: BlockCSR, P: BlockCSR) -> PtAPCache:
     r_indptr, r_indices, r_perm = transpose_structure(P.indptr, P.indices,
                                                       P.nbc)
     # R is (n_coarse x n_fine) with (bs_c x bs_f) blocks
-    R_struct = BlockCSR(r_indptr, r_indices,
-                        jnp.zeros((P.nnzb, P.bc, P.br), P.data.dtype),
-                        P.nbr, state_token=P.state_token)
+    R_struct = structure_bcsr(r_indptr, r_indices, P.nbr, P.bc, P.br,
+                              P.data.dtype, state_token=P.state_token)
     ap_plan = spgemm_symbolic(A, P)
-    AP_struct = BlockCSR(ap_plan.indptr, ap_plan.indices,
-                         jnp.zeros((ap_plan.nnzb, ap_plan.br, ap_plan.bc),
-                                   A.data.dtype),
-                         ap_plan.nbc, state_token=0)
+    AP_struct = structure_bcsr(ap_plan.indptr, ap_plan.indices, ap_plan.nbc,
+                               ap_plan.br, ap_plan.bc, A.data.dtype)
     ac_plan = spgemm_symbolic(R_struct, AP_struct)
     return PtAPCache(r_indptr=r_indptr, r_indices=r_indices, r_perm=r_perm,
                      ap_plan=ap_plan, ac_plan=ac_plan,

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.block_csr import BlockCSR
+from repro.core.lanes import block_matmul_lanes, from_lanes, gather_lanes, \
+    segment_sum_lanes, to_lanes
 
 Array = jax.Array
 
@@ -275,18 +277,15 @@ def spgemm_numeric_data(plan: SpGEMMPlan, a_data: Array, b_data: Array, *,
     from repro.kernels import backend as _backend
     if path is None and use_kernel is not None:
         path = "pairs" if use_kernel else "reference"
-    path = _backend.resolve_spgemm_path(path)
-    interpret = _backend.resolve_interpret(interpret)
+    path = _backend.resolve_spgemm_path(path, a_data.dtype)
     if path == "fused":
         return _fused_numeric(plan, a_data, b_data, interpret=interpret,
                               tile_slots=tile_slots,
                               accum_dtype=accum_dtype)
-    pa = jnp.asarray(plan.pair_a)
-    pb = jnp.asarray(plan.pair_b)
     seg = jnp.asarray(plan.out_idx)
-    lhs = a_data[pa]                     # (npairs, br, bk)
-    rhs = b_data[pb]                     # (npairs, bk, bc)
     if path == "pairs":
+        lhs = a_data[jnp.asarray(plan.pair_a)]           # (npairs, br, bk)
+        rhs = b_data[jnp.asarray(plan.pair_b)]           # (npairs, bk, bc)
         # cast the operands up *before* the kernel chain so the pair
         # products stay at the accumulator between block_pair_gemm and
         # block_seg_sum (rounding each product back to the payload dtype
@@ -299,15 +298,20 @@ def spgemm_numeric_data(plan: SpGEMMPlan, a_data: Array, b_data: Array, *,
         from repro.kernels.block_seg_sum import ops as _ks
         out = _ks.block_seg_sum(prod, seg, plan.nnzb, interpret=interpret)
         return out.astype(a_data.dtype)
+    # reference: lane-dense pair products (pairs on the minor axis), one
+    # flat sorted segment sum
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None else a_data.dtype
-    prod = jnp.einsum("pij,pjk->pik", lhs.astype(acc), rhs.astype(acc),
-                      preferred_element_type=acc)
-    return jax.ops.segment_sum(prod, seg, num_segments=plan.nnzb,
-                               indices_are_sorted=True).astype(a_data.dtype)
+    br, bk, bc = a_data.shape[1], a_data.shape[2], b_data.shape[2]
+    lhs = gather_lanes(to_lanes(a_data.astype(acc)), plan.pair_a)
+    rhs = gather_lanes(to_lanes(b_data.astype(acc)), plan.pair_b)
+    prod = block_matmul_lanes(lhs.reshape(br, bk, -1),
+                              rhs.reshape(bk, bc, -1))
+    out = segment_sum_lanes(prod.reshape(br * bc, -1), seg, plan.nnzb)
+    return from_lanes(out, (br, bc)).astype(a_data.dtype)
 
 
 def _fused_numeric(plan: SpGEMMPlan, a_data: Array, b_data: Array, *,
-                   interpret: bool, tile_slots: int | None = None,
+                   interpret: bool | None, tile_slots: int | None = None,
                    accum_dtype=None) -> Array:
     """One-pass numeric phase over the tiled plan layout.
 
@@ -317,22 +321,23 @@ def _fused_numeric(plan: SpGEMMPlan, a_data: Array, b_data: Array, *,
     block in VMEM.  No array of shape ``(npairs, br, bc)`` is ever built.
     """
     from repro.kernels.fused_pair_gemm import ops as _kf
-    ta = jnp.asarray(plan.tile_pair_a)
-    tb = jnp.asarray(plan.tile_pair_b)
-    mask = jnp.asarray(plan.tile_mask)
-    lhs = jnp.where(mask[..., None, None], a_data[ta], 0)
-    rhs = b_data[tb]                     # (tile_rows, kmax, bk, bc)
-    out = _kf.fused_pair_gemm(lhs, rhs, interpret=interpret,
-                              tile_slots=tile_slots,
-                              accum_dtype=accum_dtype)
+    br, bk, bc = a_data.shape[1], a_data.shape[2], b_data.shape[2]
+    ta = jnp.asarray(plan.tile_pair_a).T     # (kmax, tile_rows)
+    mask = jnp.asarray(plan.tile_mask).T
+    # operands gathered lane-dense: (br, bk, kmax, tile_rows)
+    lhs = jnp.where(mask, gather_lanes(to_lanes(a_data), ta), 0)
+    rhs = gather_lanes(to_lanes(b_data), jnp.asarray(plan.tile_pair_b).T)
+    out = _kf.fused_pair_gemm_lanes(
+        lhs.reshape((br, bk) + ta.shape), rhs.reshape((bk, bc) + ta.shape),
+        interpret=interpret, tile_slots=tile_slots, accum_dtype=accum_dtype)
+    out = out.reshape(br * bc, -1)
     if plan.tile_identity:
-        return out
+        return from_lanes(out, (br, bc))
     # histogram-forced row splits: combine the O(nnzb)-sized row partials
     # (never the O(npairs) pair products), at the accumulator dtype
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None else out.dtype
-    return jax.ops.segment_sum(out.astype(acc), jnp.asarray(plan.tile_seg),
-                               num_segments=plan.nnzb,
-                               indices_are_sorted=True).astype(out.dtype)
+    out = segment_sum_lanes(out.astype(acc), plan.tile_seg, plan.nnzb)
+    return from_lanes(out, (br, bc)).astype(a_data.dtype)
 
 
 def spgemm_numeric(plan: SpGEMMPlan, A: BlockCSR, B: BlockCSR, **kw
@@ -392,10 +397,15 @@ def block_axpy_symbolic(X: BlockCSR, Y: BlockCSR) -> BlockAXPYPlan:
 def block_axpy_numeric_data(plan: BlockAXPYPlan, alpha, x_data: Array,
                             y_data: Array) -> Array:
     br, bc = x_data.shape[1], x_data.shape[2]
-    out = jnp.zeros((plan.nnzb, br, bc), x_data.dtype)
-    out = out.at[jnp.asarray(plan.x_slot)].add(alpha * x_data)
-    out = out.at[jnp.asarray(plan.y_slot)].add(y_data)
-    return out
+    # flat element scatters with the block count on the lanes
+    n = plan.nnzb
+    rows = jnp.arange(br * bc, dtype=jnp.int32)[:, None] * n
+    out = jnp.zeros((br * bc * n,), x_data.dtype)
+    out = out.at[(rows + jnp.asarray(plan.x_slot, jnp.int32)).reshape(-1)
+                 ].add(to_lanes(alpha * x_data).reshape(-1))
+    out = out.at[(rows + jnp.asarray(plan.y_slot, jnp.int32)).reshape(-1)
+                 ].add(to_lanes(y_data).reshape(-1))
+    return from_lanes(out.reshape(br * bc, n), (br, bc))
 
 
 def block_axpy(alpha, X: BlockCSR, Y: BlockCSR) -> BlockCSR:

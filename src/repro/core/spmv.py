@@ -20,22 +20,64 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.block_csr import BlockCSR, BlockELL, EllTransposePlan
+from repro.core.lanes import gather_lanes, to_lanes
 from repro.obs import trace as obs_trace
 
 Array = jax.Array
 
 
+def ell_contract(data: Array, x_blocks: Array, indices) -> Array:
+    """``y[r,a(,m)] = sum_{k,b} data[r,k,a,b] * x_blocks[indices[r,k],b(,m)]``
+    for an ELL payload ``(nbr, kmax, br, bc)`` and a block vector
+    ``(nbc, bc)`` or panel ``(nbc, bc, m)``.
+
+    ``x`` is gathered lane-dense, ``(bc, m, kmax, nbr)``, by one flat
+    element gather (``repro.core.lanes``): a row-major ``(nbr, kmax, bc)``
+    gather pads its small minor dims to the TPU's (8, 128) tile and takes
+    the TPU compiler seconds per instance.  Summed in the Pallas kernels'
+    order — per slot sequentially in ``b``, then over the slots — so the
+    XLA and kernel paths of a smoother agree to the bit in f64.
+    """
+    xb = x_blocks if x_blocks.ndim == 3 else x_blocks[..., None]
+    nbc, bc, m = xb.shape
+    idx_t = jnp.asarray(indices).T                # (kmax, nbr)
+    xg = gather_lanes(xb.reshape(nbc, bc * m).T, idx_t).reshape(
+        (bc, m) + idx_t.shape)
+    d = jnp.transpose(data, (2, 3, 1, 0))[:, :, None]  # (br, bc, 1, kmax, r)
+    out = []
+    for a in range(d.shape[0]):
+        t = d[a, 0] * xg[0]
+        for b in range(1, bc):
+            t = t + d[a, b] * xg[b]
+        out.append(jnp.sum(t, axis=1))            # (m, nbr)
+    y = jnp.transpose(jnp.stack(out), (2, 0, 1))  # (nbr, br, m)
+    return y if x_blocks.ndim == 3 else y[..., 0]
+
+
+def block_matvec(mats: Array, v: Array) -> Array:
+    """``y[n,a(,m)] = sum_c mats[n,a,c] * v[n,c(,m)]``, sequential in ``c``
+    — the kernels' order, like ``ell_contract``."""
+    if v.ndim == 2:
+        terms = (mats[..., c] * v[:, c, None] for c in range(mats.shape[2]))
+    else:
+        terms = (mats[..., c, None] * v[:, None, c]
+                 for c in range(mats.shape[2]))
+    acc = next(terms)
+    for t in terms:
+        acc = acc + t
+    return acc
+
+
 @jax.jit
 def spmv_ell(ell: BlockELL, x: Array) -> Array:
-    """y = A @ x on the padded ELL layout.  x: (nbc*bc,) -> y: (nbr*br,)."""
+    """y = A @ x on the padded ELL layout.  x: (nbc*bc,) -> y: (nbr*br,).
+
+    Padded slots point at column 0 with exactly-zero data blocks, so they
+    contribute nothing."""
     with obs_trace.span("spmv_ell"):
-        nbc, bc, br = ell.nbc, ell.bc, ell.br
-        xb = x.reshape(nbc, bc)
-        gathered = xb[ell.indices]  # (nbr, kmax, bc); padded rows hit col 0,
-        # but padded data blocks are exactly zero so they contribute nothing.
-        y = jnp.einsum("rkab,rkb->ra", ell.data, gathered,
-                       preferred_element_type=ell.data.dtype)
-        return y.reshape(ell.nbr * br)
+        xb = x.reshape(ell.nbc, ell.bc)
+        return ell_contract(ell.data, xb, ell.indices).reshape(
+            ell.nbr * ell.br)
 
 
 @jax.jit
@@ -47,15 +89,12 @@ def spmm_ell(ell: BlockELL, X: Array) -> Array:
     layer's k=1 exactness contract rests on this.
     """
     with obs_trace.span("spmm_ell"):
-        nbc, bc, br = ell.nbc, ell.bc, ell.br
         m = X.shape[1]
         if m == 1:
             return spmv_ell(ell, X[:, 0])[:, None]
-        xb = X.reshape(nbc, bc, m)
-        gathered = xb[ell.indices]  # (nbr, kmax, bc, m)
-        y = jnp.einsum("rkab,rkbm->ram", ell.data, gathered,
-                       preferred_element_type=ell.data.dtype)
-        return y.reshape(ell.nbr * br, m)
+        xb = X.reshape(ell.nbc, ell.bc, m)
+        return ell_contract(ell.data, xb, ell.indices).reshape(
+            ell.nbr * ell.br, m)
 
 
 @jax.jit
@@ -66,17 +105,24 @@ def apply_ell_t(ell: BlockELL, pt: EllTransposePlan, x: Array) -> Array:
     flattened ``(nbr*kmax, br, bc)`` payload, so the restriction reuses the
     prolongator's value stream byte-for-byte — the stored ``r_ell``
     duplicate is gone from the hierarchy.  Padded plan slots point at slot
-    0 (a real block) and are zeroed by the mask.  Panel-polymorphic like
-    ``apply_ell``: ``x`` is ``(nbr*br,)`` or ``(nbr*br, k)``.
+    0 (a real block) and are zeroed by the mask.  The blocks are contracted
+    transposed, which is exactly the stored-``r_ell`` apply's operand, in
+    the same order.  Panel-polymorphic like ``apply_ell``: ``x`` is
+    ``(nbr*br,)`` or ``(nbr*br, k)``.
     """
     with obs_trace.span("apply_ell_t"):
         nbr, kmax, br, bc = ell.data.shape
-        blocks = ell.data.reshape(nbr * kmax, br, bc)[pt.gather]
-        blocks = jnp.where(pt.mask[..., None, None], blocks, 0)
+        flat = to_lanes(ell.data.reshape(nbr * kmax, br, bc))
+        mask_t = jnp.asarray(pt.mask).T
+        blocks = jnp.where(
+            mask_t, gather_lanes(flat, jnp.asarray(pt.gather).T), 0)
+        # (nbc, tkmax, bc, br): the stored restriction's own payload,
+        # materialized (not fused into the contraction) so the multiply-add
+        # chain is the stored apply's, bit for bit
+        r_data = jax.lax.optimization_barrier(jnp.transpose(
+            blocks.reshape((br, bc) + mask_t.shape), (3, 2, 1, 0)))
         xb = x.reshape((nbr, br) + x.shape[1:])
-        xg = xb[pt.rows]                        # (nbc, tkmax, br[, k])
-        y = jnp.einsum("ckab,cka...->cb...", blocks, xg,
-                       preferred_element_type=ell.data.dtype)
+        y = ell_contract(r_data, xb, pt.rows)
         return y.reshape((ell.nbc * bc,) + x.shape[1:])
 
 
@@ -115,8 +161,8 @@ def spmv(A, x: Array, *, use_kernel: bool | None = None,
     """Front door: accepts BlockCSR (converts) or BlockELL.
 
     ``use_kernel=None`` / ``interpret=None`` resolve per backend: the Pallas
-    kernel compiled natively on TPU, the jnp reference elsewhere (see
-    ``repro.kernels.backend``).  ``tile_rows=None`` resolves through the
+    kernel compiled natively on TPU for an f32/bf16 payload, the jnp
+    reference elsewhere (see ``repro.kernels.backend``).  ``tile_rows=None`` resolves through the
     autotuner (``repro.kernels.autotune``, governed by ``REPRO_TUNE``) with
     the static default as fallback.  ``accum_dtype`` threads the kernel
     accumulator rule (None = native; the jnp reference path accumulates
@@ -124,10 +170,9 @@ def spmv(A, x: Array, *, use_kernel: bool | None = None,
     """
     from repro.kernels import backend as _backend
     ell = A.to_ell() if isinstance(A, BlockCSR) else A
-    if _backend.resolve_use_kernel(use_kernel):
+    if _backend.resolve_use_kernel(use_kernel, ell.data.dtype):
         from repro.kernels.block_spmv import ops as _k
-        return _k.block_spmv(ell, x,
-                             interpret=_backend.resolve_interpret(interpret),
+        return _k.block_spmv(ell, x, interpret=interpret,
                              tile_rows=tile_rows, accum_dtype=accum_dtype)
     return spmv_ell(ell, x)
 
@@ -146,10 +191,9 @@ def spmm(A, X: Array, *, path: str | None = None,
     """
     from repro.kernels import backend as _backend
     ell = A.to_ell() if isinstance(A, BlockCSR) else A
-    if _backend.resolve_spmm_path(path) == "kernel":
+    if _backend.resolve_spmm_path(path, ell.data.dtype) == "kernel":
         from repro.kernels.block_spmm import ops as _k
-        return _k.block_spmm(ell, X,
-                             interpret=_backend.resolve_interpret(interpret),
+        return _k.block_spmm(ell, X, interpret=interpret,
                              tile_rows=tile_rows, accum_dtype=accum_dtype)
     return spmm_ell(ell, X)
 

@@ -13,8 +13,10 @@ aggregate), padded rows of Q are exactly zero and are simply not stored.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -47,11 +49,29 @@ def tentative_prolongator(aggr: Aggregation, B: Array, bs_f: int
     starts = np.cumsum(starts)
     pos_in_agg = np.arange(n_nodes) - starts[agg_sorted]
 
+    inv = np.empty(n_nodes, dtype=np.int64)
+    inv[order] = np.arange(n_nodes)
+    p_data, B_c = _tentative_numeric(B, order, agg_sorted, pos_in_agg, inv,
+                                   n_agg=aggr.n_agg, max_sz=max_sz,
+                                   bs_f=bs_f)
+    indptr = np.arange(n_nodes + 1, dtype=np.int64)
+    indices = aggr.node_to_agg.astype(np.int32)
+    P = BlockCSR.from_arrays(indptr, indices, p_data, aggr.n_agg)
+    return P, B_c
+
+
+@partial(jax.jit, static_argnames=("n_agg", "max_sz", "bs_f"))
+def _tentative_numeric(B, order, agg_sorted, pos_in_agg, inv, *,
+                       n_agg: int, max_sz: int, bs_f: int):
+    """Device part of ``tentative_prolongator``, one program: per-node
+    ``(bs_f, nns)`` blocks of P~ in node order, and the coarse near-null
+    space (each aggregate's R, stacked)."""
+    nns = B.shape[1]
     # padded per-aggregate near-null blocks: (n_agg, max_sz, bs_f, nns)
-    Bn = B.reshape(n_nodes, bs_f, nns)
-    padded = jnp.zeros((aggr.n_agg, max_sz, bs_f, nns), B.dtype)
+    Bn = B.reshape(-1, bs_f, nns)
+    padded = jnp.zeros((n_agg, max_sz, bs_f, nns), B.dtype)
     padded = padded.at[agg_sorted, pos_in_agg].set(Bn[order])
-    stacked = padded.reshape(aggr.n_agg, max_sz * bs_f, nns)
+    stacked = padded.reshape(n_agg, max_sz * bs_f, nns)
 
     Q, R = jnp.linalg.qr(stacked)            # (n_agg, max_sz*bs_f, nns)
     # sign-fix for determinism: positive R diagonal
@@ -60,15 +80,7 @@ def tentative_prolongator(aggr: Aggregation, B: Array, bs_f: int
     Q = Q * sgn[:, None, :]
     R = R * sgn[:, :, None]
 
-    # extract each node's (bs_f x nns) slice of its aggregate's Q
-    Qb = Q.reshape(aggr.n_agg, max_sz, bs_f, nns)
-    p_data = Qb[agg_sorted, pos_in_agg]      # (n_nodes, bs_f, nns) sorted
-    # back to node order; one block per node row, column = aggregate
-    inv = np.empty(n_nodes, dtype=np.int64)
-    inv[order] = np.arange(n_nodes)
-    p_data = p_data[inv]
-    indptr = np.arange(n_nodes + 1, dtype=np.int64)
-    indices = aggr.node_to_agg.astype(np.int32)
-    P = BlockCSR.from_arrays(indptr, indices, p_data, aggr.n_agg)
-    B_c = R.reshape(aggr.n_agg * nns, nns)
-    return P, B_c
+    # extract each node's (bs_f x nns) slice of its aggregate's Q, then
+    # back to node order (one block per node row, column = aggregate)
+    Qb = Q.reshape(n_agg, max_sz, bs_f, nns)
+    return Qb[agg_sorted, pos_in_agg][inv], R.reshape(n_agg * nns, nns)

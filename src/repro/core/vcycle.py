@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.block_csr import BlockELL, EllTransposePlan
-from repro.core.spmv import apply_ell, apply_ell_t
+from repro.core.spmv import apply_ell, apply_ell_t, block_matvec
 from repro.obs import trace as obs_trace
 from repro.robust import inject
 
@@ -86,6 +86,7 @@ def fine_operator(hier: Hierarchy) -> BlockELL:
         else hier.levels[0].a_ell
 
 
+@jax.jit
 def pbjacobi_apply(dinv: Array, r: Array) -> Array:
     """Point-block Jacobi apply; ``r`` is ``(n,)`` or a panel ``(n, k)``.
 
@@ -96,9 +97,7 @@ def pbjacobi_apply(dinv: Array, r: Array) -> Array:
     """
     nbr, bs = dinv.shape[0], dinv.shape[1]
     rb = r.reshape((nbr, bs) + r.shape[1:])
-    out = jnp.einsum("nab,nb...->na...", dinv, rb,
-                     preferred_element_type=dinv.dtype)
-    return out.reshape((nbr * bs,) + r.shape[1:])
+    return block_matvec(dinv, rb).reshape((nbr * bs,) + r.shape[1:])
 
 
 def chebyshev_recurrence(spmv, pbj, lam_max: Array, b: Array, x: Array,
@@ -161,10 +160,8 @@ def pbjacobi_smooth(lv: LevelState, b: Array, x: Array,
 def _fused_step(lv: LevelState, b: Array, x: Array, d: Array, c1, c2):
     """One fused recurrence step ``d' = c1*d + c2*D^{-1}(b - A x);
     x' = x + d'`` through the single-pass Pallas kernel."""
-    from repro.kernels import backend as _backend
     from repro.kernels.fused_smoother import ops as _fs
-    return _fs.smoother_step(lv.a_ell, lv.dinv, b, x, d, c1, c2,
-                             interpret=_backend.resolve_interpret(None))
+    return _fs.smoother_step(lv.a_ell, lv.dinv, b, x, d, c1, c2)
 
 
 def chebyshev_smooth_fused(lv: LevelState, b: Array, x: Array,
@@ -215,7 +212,7 @@ def apply_smoother(lv, b, x, smoother: str, degree: int,
     legacy path).  Resolution happens at trace time, like the other knobs.
     """
     from repro.kernels.backend import resolve_smooth_path
-    if resolve_smooth_path(path) == "fused":
+    if resolve_smooth_path(path, lv.a_ell.data.dtype) == "fused":
         if smoother == "chebyshev":
             return chebyshev_smooth_fused(lv, b, x, degree=degree)
         return pbjacobi_smooth_fused(lv, b, x, its=degree)

@@ -53,7 +53,6 @@ def measured_cycle_comm(dg, mesh) -> dict:
     the recompute-differenced count (see module docstring).
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.dist import solver as ds
@@ -82,8 +81,8 @@ def measured_cycle_comm(dg, mesh) -> dict:
         return ds._rank_vcycle(dg, args, states, chol, b, overlap)[None]
 
     def trace(f, *xs):
-        sm = shard_map(f, mesh, in_specs=(P(ds.AXIS),) * len(xs),
-                       out_specs=P(ds.AXIS), check_rep=False)
+        sm = jax.shard_map(f, mesh=mesh, in_specs=(P(ds.AXIS),) * len(xs),
+                           out_specs=P(ds.AXIS), check_vma=False)
         return str(jax.make_jaxpr(sm)(*xs))
 
     rec = count_collectives(trace(recompute_only, args, a0), dg.ndev)
@@ -98,15 +97,14 @@ def main(m: int, pr: int, pc: int) -> int:
 
     from repro.core import gamg
     from repro.dist.partition import ProcessMesh
-    from repro.dist.solver import build_dist_gamg
+    from repro.dist.solver import build_dist_gamg, rank_mesh
     from repro.fem.assemble import assemble_elasticity
     from repro.obs.model import dist_cycle_comm
 
     assert len(jax.devices()) >= pr, \
         (f"need XLA_FLAGS=--xla_force_host_platform_device_count={pr}, "
          f"got {len(jax.devices())} devices")
-    from jax.sharding import Mesh
-    mesh = Mesh(np.array(jax.devices()[:pr]), ("rank",))
+    mesh = rank_mesh(jax.devices()[:pr])
     prob = assemble_elasticity(m)
     setupd = gamg.setup(prob.A, prob.B, coarse_size=30, precision="f64")
     dg = build_dist_gamg(setupd, ProcessMesh((pr, pc)))
@@ -121,6 +119,9 @@ def main(m: int, pr: int, pc: int) -> int:
 
 
 if __name__ == "__main__":
+    # counts collectives on fake CPU devices; never takes an accelerator
+    import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     sys.exit(main(int(sys.argv[1]) if len(sys.argv) > 1 else 5,
                   int(sys.argv[2]) if len(sys.argv) > 2 else 2,
                   int(sys.argv[3]) if len(sys.argv) > 3 else 1))

@@ -92,13 +92,17 @@ def main(m: int) -> int:
     flags = os.environ.get("XLA_FLAGS", "")
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={ndev} " + flags)
+    # a fake-device tool by design: it never takes an accelerator, so it
+    # cannot contend with the process that holds one
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
     import numpy as np
 
     import repro.core  # noqa: F401  (x64 on)
     from repro.core import gamg
-    from repro.dist.solver import build_dist_gamg, make_dist_solver
+    from repro.dist.solver import build_dist_gamg, make_dist_solver, \
+        rank_mesh
     from repro.fem.assemble import assemble_elasticity
 
     assert len(jax.devices()) == ndev, (jax.devices(), ndev)
@@ -115,7 +119,7 @@ def main(m: int) -> int:
 
     # distributed: cold staging + hot solve (placement pinned fully
     # sharded — the agglomerated placement is checked against this below)
-    mesh = jax.make_mesh((ndev,), ("rank",))
+    mesh = rank_mesh(jax.devices())
     dg = build_dist_gamg(setupd, ndev, coarse_eq_limit=0)
     args = dg.sharded_args(setupd)
     run = make_dist_solver(dg, setupd, mesh, rtol=1e-8, maxiter=200)
@@ -415,7 +419,6 @@ def main(m: int) -> int:
         print("post-fault re-staging parity: identical")
 
     if os.environ.get("REPRO_SELFTEST_OVERLAP") == "1":
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         from repro.core.block_csr import BlockCSR
@@ -502,8 +505,8 @@ def main(m: int) -> int:
                 y1 = pamg.combine_split(msk, yi, yb)
                 return y0[None], y1[None]
 
-            f = shard_map(rank, mesh, in_specs=(P_("rank"),) * 5,
-                          out_specs=P_("rank"), check_rep=False)
+            f = jax.shard_map(rank, mesh=mesh, in_specs=(P_("rank"),) * 5,
+                              out_specs=P_("rank"), check_vma=False)
             y0, y1 = jax.jit(f)(*stack, jax.numpy.asarray(x_slabs))
             np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
 
@@ -560,9 +563,9 @@ def main(m: int) -> int:
                 a_j["a_idx"], dat_j,
                 pamg.halo_window(x[0], op_j.halo))[None]
 
-        jaxprs = [str(jax.make_jaxpr(shard_map(
-            f, mesh, in_specs=P_("rank"), out_specs=P_("rank"),
-            check_rep=False))(jax.numpy.asarray(xs_j)))
+        jaxprs = [str(jax.make_jaxpr(jax.shard_map(
+            f, mesh=mesh, in_specs=P_("rank"), out_specs=P_("rank"),
+            check_vma=False))(jax.numpy.asarray(xs_j)))
             for f in (routed, handrolled)]
         assert jaxprs[0] == jaxprs[1], \
             "REPRO_OVERLAP=off left residue vs the blocking apply"

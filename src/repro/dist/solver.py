@@ -64,8 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec
+from jax.sharding import AxisType, Mesh, PartitionSpec
 
 from repro.core.block_csr import BlockCSR, BlockELL, EllTransposePlan
 from repro.core.gamg import GAMGSetup, LevelSetup, coarse_cholesky, \
@@ -73,7 +72,8 @@ from repro.core.gamg import GAMGSetup, LevelSetup, coarse_cholesky, \
 from repro.core.krylov import wrap_precond
 from repro.core.precision import PrecisionPolicy
 from repro.core.ptap import ptap_numeric_data
-from repro.core.spmv import apply_ell, apply_ell_t
+from repro.core.smooth import invert_diag_blocks
+from repro.core.spmv import apply_ell, apply_ell_t, block_matvec
 from repro.core.vcycle import (
     LevelState,
     apply_restriction,
@@ -117,6 +117,17 @@ DEFAULT_COARSE_EQ_LIMIT = 50
 
 Array = jax.Array
 P = PartitionSpec
+
+
+def rank_mesh(devices) -> Mesh:
+    """The 1-D ``"rank"`` mesh the dist programs run on, over ``devices``.
+
+    The axis is ``Auto``: the per-rank programs are ``shard_map`` bodies,
+    and their sharded outputs (status, iterate) must stay indexable by the
+    caller, which an ``Explicit`` axis (``jax.make_mesh``'s default) does
+    not allow.
+    """
+    return Mesh(np.asarray(devices), (AXIS,), axis_types=(AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------
@@ -672,7 +683,7 @@ def _rank_recompute(dg: DistGAMG, args, a_slab: Array, overlap: bool):
         eye = jnp.eye(lv.bs, dtype=h)
         diag = jnp.where(a["diag_mask"][:, None, None], a_cur[a["diag_sel"]],
                          eye)
-        dinv = jnp.linalg.inv(
+        dinv = invert_diag_blocks(
             diag.astype(policy.factor_dtype)).astype(h)
         dinva = jnp.einsum("rab,rkbc->rkac", dinv.astype(acc_p),
                            a_ell_data.astype(acc_p),
@@ -815,9 +826,8 @@ def _rank_smooth(dg: DistGAMG, spmv, st, b: Array, x: Array) -> Array:
                             jnp.dtype(dg.precision.accum_dtype))
 
     def pbj(r):
-        return jnp.einsum("nab,nb...->na...", st["dinv"].astype(acc),
-                          r.astype(acc),
-                          preferred_element_type=acc).astype(r.dtype)
+        return block_matvec(st["dinv"].astype(acc),
+                            r.astype(acc)).astype(r.dtype)
 
     if dg.smoother == "chebyshev":
         return chebyshev_recurrence(spmv, pbj, st["lam"], b, x, dg.degree)
@@ -1116,8 +1126,8 @@ def make_dist_solver(dg: DistGAMG, setupd: GAMGSetup, mesh, *,
             return rank_body(args, a0, b, None)
         in_specs = (P(AXIS),) * 3
 
-    sharded = shard_map(rank_fn, mesh, in_specs=in_specs,
-                        out_specs=P(AXIS), check_rep=False)
+    sharded = jax.shard_map(rank_fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(AXIS), check_vma=False)
     return _with_rank0_span(jax.jit(sharded), "dist/solve")
 
 
@@ -1168,8 +1178,8 @@ def make_dist_coeff_solver(dg: DistGAMG, da: DistAssembly, mesh, *,
             return rank_body(args, aargs, E, nu, b, None)
         in_specs = (P(AXIS),) * 5
 
-    sharded = shard_map(rank_fn, mesh, in_specs=in_specs,
-                        out_specs=P(AXIS), check_rep=False)
+    sharded = jax.shard_map(rank_fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(AXIS), check_vma=False)
     return _with_rank0_span(jax.jit(sharded), "dist/coeff_solve")
 
 

@@ -8,7 +8,7 @@ is then a single device scatter-sum of the block value stream.
 Two assembly paths share the one ``BlockCOOPlan``:
 
 ``path="device"`` (default)
-    per-element stiffness blocks computed in JAX by vmapped quadrature
+    per-element stiffness blocks computed in JAX from Lame-parameter fields
     (``repro.fem.device_stiffness``) from per-element material fields
     ``E(x), nu(x)`` — heterogeneous and jittable.  The problem carries a
     ``DeviceAssembler`` whose ``coo_data(E, nu)`` composes with
@@ -82,7 +82,7 @@ class ElasticityProblem:
 
     # ---- coefficient updates (device path) ------------------------------
     def coefficient_operator(self, E, nu) -> BlockCSR:
-        """Pure re-assembly from new per-element fields: vmapped quadrature
+        """Pure re-assembly from new per-element fields: element blocks
         -> cached COO scatter.  Does not mutate the problem."""
         if self.assembler is None:
             raise ValueError(
@@ -166,7 +166,7 @@ def assemble_elasticity(m: int, order: int = 1, E=1.0, nu=0.3,
 
     ``E``/``nu`` may be scalars or per-element ``(n_elements,)`` arrays
     (heterogeneous materials).  ``path`` selects where the element blocks
-    are computed: ``"device"`` (JAX vmapped quadrature, default — carries a
+    are computed: ``"device"`` (JAX element blocks, default — carries a
     ``DeviceAssembler`` for jitted coefficient updates) or ``"host"`` (the
     numpy golden reference).
     """
@@ -202,7 +202,7 @@ def assemble_elasticity(m: int, order: int = 1, E=1.0, nu=0.3,
     if path == "device":
         assembler = DeviceAssembler.build(mesh, plan)
         Ej, nuj = assembler.as_fields(E_f, nu_f)
-        values = assembler.value_stream(Ej, nuj)
+        values = jax.jit(assembler.value_stream)(Ej, nuj)
     else:
         Ej = nuj = None
         if np.all(E_f == E_f[0]) and np.all(nu_f == nu_f[0]):

@@ -37,15 +37,16 @@ import numpy as np
 
 from repro.kernels.backend import backend, resolve_interpret, resolve_tune
 
-# candidate grids per family, keyed by the tile-parameter name; the static
-# default each front door falls back to MUST be a member, so "sweep" can
-# only ever match-or-beat the untuned path
+# candidate grids per family, keyed by the tile-parameter name: lanes
+# (rows or slots) per grid step, multiples of the 128-lane tile; without
+# a cached winner the front doors size the tile from the VMEM budget.  The
+# ELL kernels gather x per 128-row tile, so their row tile is fixed.
 CANDIDATES = {
-    "block_spmv": {"tile_rows": (4, 8, 16, 32, 64)},
-    "block_spmm": {"tile_rows": (4, 8, 16, 32), "pad_k_to": (1, 4, 8)},
-    "pbjacobi": {"tile_rows": (16, 32, 64, 128, 256)},
-    "fused_smoother": {"tile_rows": (4, 8, 16, 32, 64)},
-    "fused_pair_gemm": {"tile_slots": (32, 64, 128, 256)},
+    "block_spmv": {"tile_rows": (128,)},
+    "block_spmm": {"tile_rows": (128,), "pad_k_to": (1, 4, 8)},
+    "pbjacobi": {"tile_rows": (128, 256, 512, 1024, 2048)},
+    "fused_smoother": {"tile_rows": (128,)},
+    "fused_pair_gemm": {"tile_slots": (128, 256, 512, 1024)},
 }
 
 _memo: dict = {}

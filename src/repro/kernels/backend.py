@@ -8,8 +8,15 @@ interpreter.  This module centralizes the decision:
                            overridable with ``REPRO_BACKEND`` for testing.
 * ``resolve_interpret``  — ``None`` means "interpret only when no accelerator
                            can compile the kernel" (i.e. CPU).
+* ``kernel_interpret``   — the same for a kernel front door, and refuses a
+                           compiled call with an f64 payload (Mosaic has
+                           no 64-bit floats) with a ``ValueError`` naming
+                           the ``f32`` policy.
 * ``resolve_use_kernel`` — ``None`` means "use the Pallas kernels exactly when
-                           they compile natively" (TPU).
+                           they compile natively": on TPU, for an f32/bf16
+                           payload (``kernels_by_default``).  The path
+                           resolvers below follow the same rule, so the
+                           ``f64`` policy runs on XLA paths on a TPU.
 * ``resolve_spgemm_path``— default numeric SpGEMM path: the fused tiled
                            kernel on TPU, the einsum+segment_sum reference
                            on CPU and GPU (interpret-mode Pallas is strictly
@@ -64,14 +71,15 @@ from __future__ import annotations
 import functools
 import os
 
+import jax.numpy as jnp
+
 
 @functools.lru_cache(maxsize=None)
 def _platform() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - jax init failure
-        return "cpu"
+    # no fallback: a JAX that cannot start raises here rather than
+    # passing for a CPU run
+    import jax
+    return jax.default_backend()
 
 
 def backend() -> str:
@@ -94,6 +102,18 @@ def on_accelerator() -> bool:
     return backend() == "tpu"
 
 
+def kernel_dtype_ok(dtype) -> bool:
+    """True for payload dtypes a compiled Pallas kernel takes: Mosaic has
+    no 64-bit floats, so f64 payloads stay on the XLA paths on TPU."""
+    return dtype is None or jnp.dtype(dtype) != jnp.float64
+
+
+def kernels_by_default(dtype=None) -> bool:
+    """Default dispatch rule of every front door: the Pallas kernel exactly
+    where it compiles natively — on TPU, for a payload it can take."""
+    return on_accelerator() and kernel_dtype_ok(dtype)
+
+
 def resolve_interpret(interpret: bool | None = None) -> bool:
     """None -> interpret Pallas only where it cannot compile natively."""
     if interpret is None:
@@ -101,14 +121,27 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     return interpret
 
 
-def resolve_use_kernel(use_kernel: bool | None = None) -> bool:
+def kernel_interpret(interpret: bool | None, dtype, family: str) -> bool:
+    """``resolve_interpret`` for a Pallas front door, refusing a compiled
+    call with an f64 payload (it cannot lower) with the policy to use."""
+    interpret = resolve_interpret(interpret)
+    if not interpret and not kernel_dtype_ok(dtype):
+        raise ValueError(
+            f"{family}: compiled Pallas kernels take f32/bf16 payloads, not "
+            f"{jnp.dtype(dtype).name} (Mosaic has no 64-bit floats).  The "
+            f"'f64' precision policy runs on the XLA paths on {backend()}; "
+            f"use the 'f32' policy (precision='f32') for the kernel path.")
+    return interpret
+
+
+def resolve_use_kernel(use_kernel: bool | None = None, dtype=None) -> bool:
     """None -> dispatch to Pallas kernels exactly where they compile."""
     if use_kernel is None:
-        return on_accelerator()
+        return kernels_by_default(dtype)
     return use_kernel
 
 
-def resolve_spgemm_path(path: str | None = None) -> str:
+def resolve_spgemm_path(path: str | None = None, dtype=None) -> str:
     """Default numeric SpGEMM path for this backend.
 
     "fused"     — tiled fused pair-GEMM + in-VMEM segment reduce (no
@@ -120,7 +153,7 @@ def resolve_spgemm_path(path: str | None = None) -> str:
     if path is None:
         path = os.environ.get("REPRO_SPGEMM_PATH")
     if path is None:
-        path = "fused" if on_accelerator() else "reference"
+        path = "fused" if kernels_by_default(dtype) else "reference"
     if path not in ("fused", "pairs", "reference"):
         # ValueError, not assert: the validation must survive `python -O`,
         # and a typo'd REPRO_SPGEMM_PATH should fail loudly either way.
@@ -130,7 +163,7 @@ def resolve_spgemm_path(path: str | None = None) -> str:
     return path
 
 
-def resolve_spmm_path(path: str | None = None) -> str:
+def resolve_spmm_path(path: str | None = None, dtype=None) -> str:
     """Default multi-RHS SpMM execution path for this backend.
 
     "kernel"    — the Pallas ``block_spmm`` panel kernel (compiled on TPU,
@@ -145,7 +178,7 @@ def resolve_spmm_path(path: str | None = None) -> str:
     if path is None:
         path = os.environ.get("REPRO_SPMM_PATH")
     if path is None:
-        path = "kernel" if on_accelerator() else "reference"
+        path = "kernel" if kernels_by_default(dtype) else "reference"
     if path not in ("kernel", "reference"):
         raise ValueError(
             f"invalid SpMM path {path!r}: expected 'kernel' or 'reference' "
@@ -153,7 +186,7 @@ def resolve_spmm_path(path: str | None = None) -> str:
     return path
 
 
-def resolve_smooth_path(path: str | None = None) -> str:
+def resolve_smooth_path(path: str | None = None, dtype=None) -> str:
     """Default V-cycle smoother execution path for this backend.
 
     "fused"     — the Pallas ``fused_smoother`` kernel: one pass per
@@ -171,7 +204,7 @@ def resolve_smooth_path(path: str | None = None) -> str:
     if path is None:
         path = os.environ.get("REPRO_SMOOTH_PATH")
     if path is None:
-        path = "fused" if on_accelerator() else "reference"
+        path = "fused" if kernels_by_default(dtype) else "reference"
     if path not in ("fused", "reference"):
         raise ValueError(
             f"invalid smoother path {path!r}: expected 'fused' or "

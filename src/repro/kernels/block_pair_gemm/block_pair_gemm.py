@@ -42,7 +42,7 @@ def _pair_gemm_kernel(acc_dt, lhs_ref, rhs_ref, o_ref):
 @functools.partial(jax.jit,
                    static_argnames=("tile_pairs", "interpret", "accum_dtype"))
 def block_pair_gemm(lhs: jax.Array, rhs: jax.Array, *,
-                    tile_pairs: int = 128, interpret: bool = True,
+                    interpret: bool, tile_pairs: int = 128,
                     accum_dtype=None) -> jax.Array:
     """(npairs, br, bk) @ (npairs, bk, bc) -> (npairs, br, bc).
 
@@ -63,10 +63,10 @@ def block_pair_gemm(lhs: jax.Array, rhs: jax.Array, *,
         functools.partial(_pair_gemm_kernel, acc_dt),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tp, br, bk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tp, bk, bc), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tp, br, bk), lambda i: (i, jnp.int32(0), jnp.int32(0))),
+            pl.BlockSpec((tp, bk, bc), lambda i: (i, jnp.int32(0), jnp.int32(0))),
         ],
-        out_specs=pl.BlockSpec((tp, br, bc), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((tp, br, bc), lambda i: (i, jnp.int32(0), jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((npairs + pad, br, bc), lhs.dtype),
         interpret=interpret,
     )(lhs, rhs)

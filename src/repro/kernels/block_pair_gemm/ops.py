@@ -1,4 +1,5 @@
 """Jit'd wrapper for the batched block GEMM kernel."""
+from repro.kernels import backend
 from repro.kernels.block_pair_gemm.block_pair_gemm import (
     block_pair_gemm as _block_pair_gemm,
 )
@@ -7,7 +8,13 @@ from repro.obs import trace as obs_trace
 __all__ = ["block_pair_gemm"]
 
 
-def block_pair_gemm(*args, **kwargs):
-    """Front door with the observability span (trace-time no-op when off)."""
+def block_pair_gemm(lhs, rhs, *, interpret: bool | None = None, **kwargs):
+    """Front door with the observability span (trace-time no-op when off).
+
+    ``interpret=None`` compiles on TPU and interprets elsewhere
+    (``backend.kernel_interpret``, which refuses a compiled f64 call).
+    """
     with obs_trace.span("kernels/block_pair_gemm"):
-        return _block_pair_gemm(*args, **kwargs)
+        interpret = backend.kernel_interpret(interpret, lhs.dtype,
+                                             "block_pair_gemm")
+        return _block_pair_gemm(lhs, rhs, interpret=interpret, **kwargs)

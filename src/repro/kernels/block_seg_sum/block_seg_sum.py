@@ -46,8 +46,8 @@ def _cumsum_kernel(x_ref, o_ref, carry_ref):
 
 @functools.partial(jax.jit,
                    static_argnames=("tile_n", "interpret", "accum_dtype"))
-def block_stream_cumsum(x: jax.Array, *, tile_n: int = 256,
-                        interpret: bool = True,
+def block_stream_cumsum(x: jax.Array, *, interpret: bool,
+                        tile_n: int = 256,
                         accum_dtype=None) -> jax.Array:
     """Inclusive cumsum over axis 0 of a (n, br, bc) block stream.
 
@@ -67,8 +67,8 @@ def block_stream_cumsum(x: jax.Array, *, tile_n: int = 256,
     out = pl.pallas_call(
         _cumsum_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((tn, br, bc), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((tn, br, bc), lambda i: (i, 0, 0)),
+        in_specs=[pl.BlockSpec((tn, br, bc), lambda i: (i, jnp.int32(0), jnp.int32(0)))],
+        out_specs=pl.BlockSpec((tn, br, bc), lambda i: (i, jnp.int32(0), jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((n + pad, br, bc), acc_dt),
         scratch_shapes=[pltpu.VMEM((1, br, bc), acc_dt)],
         interpret=interpret,

@@ -11,20 +11,26 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.block_csr import BlockELL
+from repro.kernels import backend
 from repro.kernels.block_spmm.block_spmm import block_spmm_ell
 from repro.obs import trace as obs_trace
 
 
-def block_spmm(ell: BlockELL, X: jax.Array, *, interpret: bool = True,
+def block_spmm(ell: BlockELL, X: jax.Array, *, interpret: bool | None = None,
                tile_rows: int | None = None, pad_k_to: int | None = None,
                accum_dtype=None) -> jax.Array:
     """Y = A @ X, flat (n, k) panels in/out (matches core ``spmm_ell``).
 
+    ``interpret=None`` compiles on TPU and interprets elsewhere
+    (``backend.kernel_interpret``, which refuses a compiled f64 call).
     ``tile_rows=None`` / ``pad_k_to=None`` resolve through the autotuner
-    (``repro.kernels.autotune``, governed by ``REPRO_TUNE``; static
-    defaults 8/8 — the seed's hardcoded tiling).
+    (``repro.kernels.autotune``, governed by ``REPRO_TUNE``; without a
+    cached winner the lane tile comes from the VMEM budget and the panel
+    pads to 8 columns).
     """
     with obs_trace.span("kernels/block_spmm"):
+        interpret = backend.kernel_interpret(interpret, ell.data.dtype,
+                                             "block_spmm")
         k = X.shape[1]
         if tile_rows is None or pad_k_to is None:
             from repro.kernels import autotune
@@ -32,7 +38,7 @@ def block_spmm(ell: BlockELL, X: jax.Array, *, interpret: bool = True,
                        dtype=jnp.dtype(ell.data.dtype).name)
             if tile_rows is None:
                 tile_rows = autotune.resolve_param(
-                    "block_spmm", sig, "tile_rows", None, 8)
+                    "block_spmm", sig, "tile_rows", None, None)
             if pad_k_to is None:
                 pad_k_to = autotune.resolve_param(
                     "block_spmm", sig, "pad_k_to", None, 8)
@@ -41,5 +47,6 @@ def block_spmm(ell: BlockELL, X: jax.Array, *, interpret: bool = True,
         if kp != k:
             xb = jnp.pad(xb, ((0, 0), (0, 0), (0, kp - k)))
         y = block_spmm_ell(ell.indices, ell.data, xb, tile_rows=tile_rows,
-                           interpret=interpret, accum_dtype=accum_dtype)
+                           interpret=interpret, accum_dtype=accum_dtype,
+                           windows=ell.windows)
         return y.reshape(ell.nbr * ell.br, kp)[:, :k]

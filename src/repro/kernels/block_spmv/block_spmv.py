@@ -3,92 +3,37 @@
 TPU adaptation of the paper's BSR SpMV (Sec. 4.2).  A GPU BSR kernel assigns
 a warp per block row and coalesces the per-block index gather; the TPU
 analogue is *regular tiling*: the padded BlockELL layout gives every block
-row exactly ``kmax`` slots, so the kernel is a dense einsum over a
-``(TR, kmax, br, bc)`` VMEM tile plus one gather of ``x`` blocks — no
-data-dependent control flow, which is what the TPU pipeline wants.
+row exactly ``kmax`` slots, so the kernel is dense over lane tiles of rows
+with no data-dependent control flow, which is what the TPU pipeline wants.
 
-Index-traffic amortization (the paper's core argument) survives intact: the
-kernel loads one int32 per block and reuses it across the whole ``br*bc``
-payload; the ELL padding adds only zero blocks (measured padding overhead is
-reported by the benchmarks).
+Index-traffic amortization (the paper's core argument) survives: one int32
+per block addresses the whole ``br*bc`` payload; the ELL padding adds only
+zero blocks.
 
-Dtype polymorphism: the kernel accepts any floating payload dtype (f64 /
-f32 / bf16).  ``accum_dtype`` selects the accumulator the contraction runs
-in — the operands are cast up on-register, contracted at that dtype, and the
-result is rounded back to the payload dtype on the way out (the value-HBM
-traffic stays at the storage width).  ``None`` accumulates natively in the
-payload dtype, which is bitwise the pre-policy behaviour; low-precision
-inputs (bf16) should pass ``accum_dtype=jnp.float32``.
-
-Layout / tiling
-  grid        = (ceil(nbr / TR),)                sequential over row tiles
-  data tile   = (TR, kmax, br, bc)  VMEM         streamed per grid step
-  index tile  = (TR, kmax)          VMEM (int32)
-  x           = (nbc, bc)           VMEM, whole  (block-vector resident;
-                                                  fits VMEM for AMG levels —
-                                                  nbc*bc*8 B; 16 MB VMEM
-                                                  holds 2M fp64 entries)
-  out tile    = (TR, br)            VMEM
-
-For MXU alignment the wrapper pads ``TR`` to a multiple of 8 (sublane) and
-relies on ``br*bc`` small blocks being vector (VPU) work — elasticity blocks
-(3x3, 3x6, 6x6) are far below the 128-lane tile, so the einsum maps to VPU
-FMAs with the index gather amortized over the block payload, which is the
-whole point.
+The SpMV is the single-column case of the panel kernel
+(``repro.kernels.block_spmm``): same lane-dense layout (rows on the 128
+lanes, ``(br, bc)`` block dims leading), same windowed in-kernel gather
+of ``x``, same accumulator rule (``accum_dtype``; None =
+native, bf16 payloads should pass ``jnp.float32``).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.block_spmm.block_spmm import block_spmm_ell
 
 
-def _spmv_kernel(acc_dt, idx_ref, data_ref, x_ref, o_ref):
-    """One row-tile: gather x blocks, contract against the data tile."""
-    idx = idx_ref[...]                       # (TR, kmax) int32
-    tr, kmax = idx.shape
-    x = x_ref[...]                           # (nbc, bc)
-    # gather whole bc-wide blocks of x: one index per (row, slot)
-    xg = jnp.take(x, idx.reshape(-1), axis=0).reshape(tr, kmax, x.shape[1])
-    # padded slots carry exactly-zero data blocks -> contribute 0
-    o_ref[...] = jnp.einsum(
-        "rkab,rkb->ra", data_ref[...].astype(acc_dt), xg.astype(acc_dt),
-        preferred_element_type=acc_dt).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("tile_rows", "interpret", "accum_dtype"))
 def block_spmv_ell(indices: jax.Array, data: jax.Array, x_blocks: jax.Array,
-                   *, tile_rows: int = 8, interpret: bool = True,
-                   accum_dtype=None) -> jax.Array:
+                   *, interpret: bool, tile_rows: int | None = None,
+                   accum_dtype=None, windows=None) -> jax.Array:
     """y = A @ x with A in padded BlockELL form.
 
-    indices: (nbr, kmax) int32, padded slots point at block-col 0
-    data:    (nbr, kmax, br, bc), padded slots are zero blocks
+    indices:  (nbr, kmax) int32, padded slots point at block-col 0
+    data:     (nbr, kmax, br, bc), padded slots are zero blocks
     x_blocks: (nbc, bc)
-    returns  (nbr, br) at ``data.dtype``; ``accum_dtype`` sets the
-    contraction accumulator (None = native)
+    windows:  column-window plan of ``indices`` (see ``block_spmm_ell``)
+    returns   (nbr, br) at ``data.dtype``
     """
-    nbr, kmax, br, bc = data.shape
-    acc_dt = jnp.dtype(accum_dtype) if accum_dtype is not None else data.dtype
-    tr = min(tile_rows, nbr)
-    pad = (-nbr) % tr
-    if pad:
-        indices = jnp.pad(indices, ((0, pad), (0, 0)))
-        data = jnp.pad(data, ((0, pad), (0, 0), (0, 0), (0, 0)))
-    grid = ((nbr + pad) // tr,)
-    out = pl.pallas_call(
-        functools.partial(_spmv_kernel, acc_dt),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tr, kmax), lambda i: (i, 0)),
-            pl.BlockSpec((tr, kmax, br, bc), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec(x_blocks.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tr, br), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbr + pad, br), data.dtype),
-        interpret=interpret,
-    )(indices, data, x_blocks)
-    return out[:nbr]
+    return block_spmm_ell(indices, data, x_blocks[..., None],
+                          interpret=interpret, tile_rows=tile_rows,
+                          accum_dtype=accum_dtype, windows=windows)[..., 0]

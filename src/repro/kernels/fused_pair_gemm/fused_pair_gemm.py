@@ -15,21 +15,20 @@ the sorted pair list is re-packed into one fixed-width row per output block
 slot (width ``pair_kmax`` from the pair histogram, zero-padded), so
 
   * each grid step owns a contiguous run of ``TS`` output slots,
-  * the ``(br, bk) @ (bk, bc)`` contractions of a slot's pairs are unrolled
-    on-register, and
-  * the per-slot reduction accumulates entirely in VMEM — the pair-product
-    array never exists in HBM.
+  * the ``(br, bk) @ (bk, bc)`` contractions of a slot's pairs are whole
+    ``(kmax, TS)`` slab products, and
+  * the per-slot reduction runs over the slot's pairs on-register — the
+    pair-product array never exists in HBM.
 
-Layout / tiling
+Layout / tiling (lane-dense, see ``repro.kernels.tiling``)
   grid     = (ceil(nslots / TS),)
-  lhs tile = (TS, kmax, br, bk)  VMEM   gathered A blocks (padded slots = 0)
-  rhs tile = (TS, kmax, bk, bc)  VMEM   gathered B blocks
-  out tile = (TS, br, bc)        VMEM   fully reduced output blocks
+  lhs tile = (br, bk, kmax, TS)  VMEM   gathered A blocks (padded pairs = 0)
+  rhs tile = (bk, bc, kmax, TS)  VMEM   gathered B blocks
+  out tile = (br, bc, TS)        VMEM   fully reduced output blocks
 
-The contraction keeps the slot dimension on the lanes (VPU-shaped, like
-``block_pair_gemm``) and unrolls the tiny ``kmax``/``bk`` dims; with
-bs = 3..6 the kernel stays bandwidth-bound and the win is the removed
-``npairs * br * bc`` round trip plus the index bytes (paper Sec. 4.7).
+Slots sit on the 128 lanes; with bs = 3..6 the kernel stays
+bandwidth-bound and the win is the removed ``npairs * br * bc`` round trip
+plus the index bytes (paper Sec. 4.7).
 """
 from __future__ import annotations
 
@@ -39,68 +38,78 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# VMEM budget for the two operand tiles of one grid step (bytes).  Half of
-# the ~16 MB/core VMEM, leaving room for the output tile and double
-# buffering.
-_VMEM_TILE_BUDGET = 4 * 2 ** 20
+from repro.kernels import tiling
 
 
 def _fused_kernel(acc_dt, lhs_ref, rhs_ref, o_ref):
-    kmax = lhs_ref.shape[1]
-    bk = lhs_ref.shape[3]
-    acc = jnp.zeros(o_ref.shape, acc_dt)
-    for k in range(kmax):           # static unroll over the pair slots
-        lhs = lhs_ref[:, k].astype(acc_dt)   # (TS, br, bk)
-        rhs = rhs_ref[:, k].astype(acc_dt)   # (TS, bk, bc)
-        for j in range(bk):         # unroll the tiny contraction dim
-            acc = acc + lhs[:, :, j][:, :, None] * rhs[:, j, :][:, None, :]
-    o_ref[...] = acc.astype(o_ref.dtype)
+    br, bk = lhs_ref.shape[:2]
+    for i in range(br):
+        for j in range(rhs_ref.shape[1]):
+            acc = (lhs_ref[i, 0].astype(acc_dt)
+                   * rhs_ref[0, j].astype(acc_dt))
+            for c in range(1, bk):
+                acc = acc + (lhs_ref[i, c].astype(acc_dt)
+                             * rhs_ref[c, j].astype(acc_dt))
+            o_ref[i, j:j + 1, :] = jnp.sum(
+                acc, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _lane_bytes(kmax: int, br: int, bk: int, bc: int, dtype) -> int:
+    return (tiling.lane_bytes((br, bk, kmax), dtype)
+            + tiling.lane_bytes((bk, bc, kmax), dtype)
+            + tiling.lane_bytes((br, bc), dtype))
 
 
 def default_tile_slots(nslots: int, kmax: int, br: int, bk: int, bc: int,
-                       itemsize: int = 8) -> int:
-    """Pick TS so both operand tiles fit the VMEM budget."""
-    per_slot = max(1, kmax * (br * bk + bk * bc) * itemsize)
-    ts = _VMEM_TILE_BUDGET // per_slot
-    return max(1, min(256, ts, max(nslots, 1)))
+                       dtype=jnp.float32) -> int:
+    """Slots per grid step from the padded VMEM bytes of one step."""
+    return tiling.lane_tile(nslots, _lane_bytes(kmax, br, bk, bc, dtype))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tile_slots", "interpret", "accum_dtype"))
-def fused_pair_gemm(lhs: jax.Array, rhs: jax.Array, *,
-                    tile_slots: int | None = None,
-                    interpret: bool = True, accum_dtype=None) -> jax.Array:
-    """(nslots, kmax, br, bk) @ (nslots, kmax, bk, bc) -> (nslots, br, bc).
+def fused_pair_gemm_lanes(lhs: jax.Array, rhs: jax.Array, *,
+                          interpret: bool, tile_slots: int | None = None,
+                          accum_dtype=None) -> jax.Array:
+    """Lane-dense operands: ``(br, bk, kmax, nslots)`` @ ``(bk, bc, kmax,
+    nslots)`` -> ``(br, bc, nslots)``.
 
     Contracts each slot's ``kmax`` padded block pairs and reduces them into
     the slot's output block in one pass (padded pairs must be zero blocks on
-    at least one side).  ``accum_dtype`` is the VMEM accumulator dtype
-    (None = native in ``lhs.dtype``, bitwise legacy); the output rounds
-    back to ``lhs.dtype``.
+    at least one side).  ``accum_dtype`` is the accumulator dtype (None =
+    native in ``lhs.dtype``); the output rounds back to ``lhs.dtype``.
+    ``tile_slots`` is rounded up to a multiple of 128 lanes; None sizes it
+    from the VMEM budget (``default_tile_slots``).
     """
-    nslots, kmax, br, bk = lhs.shape
-    _, kmax2, bk2, bc = rhs.shape
+    br, bk, kmax, nslots = lhs.shape
+    bk2, bc, kmax2, _ = rhs.shape
     assert kmax == kmax2 and bk == bk2, (lhs.shape, rhs.shape)
-    acc_dt = jnp.dtype(accum_dtype) if accum_dtype is not None else lhs.dtype
+    dt = lhs.dtype
+    acc_dt = jnp.dtype(accum_dtype) if accum_dtype is not None else dt
     if nslots == 0 or kmax == 0:
-        return jnp.zeros((nslots, br, bc), lhs.dtype)
-    ts = tile_slots or default_tile_slots(nslots, kmax, br, bk, bc,
-                                          lhs.dtype.itemsize)
-    ts = min(ts, nslots)
-    pad = (-nslots) % ts
-    if pad:
-        lhs = jnp.pad(lhs, ((0, pad), (0, 0), (0, 0), (0, 0)))
-        rhs = jnp.pad(rhs, ((0, pad), (0, 0), (0, 0), (0, 0)))
-    grid = ((nslots + pad) // ts,)
-    out = pl.pallas_call(
+        return jnp.zeros((br, bc, nslots), dt)
+    per_lane = _lane_bytes(kmax, br, bk, bc, dt)
+    ts = tiling.lane_tile(nslots, per_lane, tile_slots)
+    return pl.pallas_call(
         functools.partial(_fused_kernel, acc_dt),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ts, kmax, br, bk), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((ts, kmax, bk, bc), lambda i: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((ts, br, bc), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nslots + pad, br, bc), lhs.dtype),
+        grid=(pl.cdiv(nslots, ts),),
+        in_specs=[tiling.lane_spec((br, bk, kmax, ts)),
+                  tiling.lane_spec((bk, bc, kmax, ts))],
+        out_specs=tiling.lane_spec((br, bc, ts)),
+        out_shape=jax.ShapeDtypeStruct((br, bc, nslots), dt),
+        compiler_params=tiling.compiler_params(per_lane * ts),
         interpret=interpret,
     )(lhs, rhs)
-    return out[:nslots]
+
+
+def fused_pair_gemm(lhs: jax.Array, rhs: jax.Array, *, interpret: bool,
+                    tile_slots: int | None = None,
+                    accum_dtype=None) -> jax.Array:
+    """Row-major blocks: (nslots, kmax, br, bk) @ (nslots, kmax, bk, bc) ->
+    (nslots, br, bc); ``fused_pair_gemm_lanes`` on the transposed
+    operands."""
+    out = fused_pair_gemm_lanes(jnp.transpose(lhs, (2, 3, 1, 0)),
+                                jnp.transpose(rhs, (2, 3, 1, 0)),
+                                interpret=interpret, tile_slots=tile_slots,
+                                accum_dtype=accum_dtype)
+    return jnp.transpose(out, (2, 0, 1))

@@ -30,7 +30,8 @@ def run_amg_dryrun(force: bool = False, m: int = 21) -> int:
     import numpy as np
     import repro.core  # noqa: F401  (x64)
     from repro.core import gamg
-    from repro.dist.solver import build_dist_gamg, make_dist_solver
+    from repro.dist.solver import build_dist_gamg, make_dist_solver, \
+        rank_mesh
     from repro.fem.assemble import assemble_elasticity
 
     results = _load_results()
@@ -45,7 +46,7 @@ def run_amg_dryrun(force: bool = False, m: int = 21) -> int:
             continue
         print(f"[run]    {key} (ndev={ndev}) ...", flush=True)
         try:
-            mesh = jax.make_mesh((ndev,), ("rank",))
+            mesh = rank_mesh(jax.devices()[:ndev])
             t0 = time.time()
             dg = build_dist_gamg(setupd, ndev)
             args = dg.sharded_args(setupd)

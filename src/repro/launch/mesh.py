@@ -15,12 +15,14 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_amg_mesh(ndev: int):
     """Flattened 1-D mesh for the distributed AMG row slabs."""
-    return jax.make_mesh((ndev,), ("rank",))
+    from repro.dist.solver import rank_mesh
+    return rank_mesh(jax.devices()[:ndev])
 
 
 def data_axes(mesh) -> tuple:

@@ -165,7 +165,7 @@ class AMGSolveServer:
     def update_coefficients(self, E, nu) -> None:
         """Hot path: new material fields (per-element arrays or scalars).
 
-        Device assembly (vmapped quadrature through the cached COO plan)
+        Device assembly (element blocks through the cached COO plan)
         fused with the state-gated recompute — the server's quasi-static
         client contract: ship two small coefficient arrays, not an
         ``(nnzb, 3, 3)`` value stream.  Fields are force-cast to the

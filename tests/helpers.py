@@ -45,3 +45,26 @@ def spd_bcsr(rng: np.random.Generator, nbr: int, bs: int,
     np.add.at(indptr, rows + 1, 1)
     return BlockCSR.from_arrays(np.cumsum(indptr), cols.astype(np.int32),
                                 blocks[rows, cols], nbr)
+
+
+def jaxpr_outputs(jaxpr) -> list:
+    """``(primitive name, output shape)`` of every equation of ``jaxpr``
+    and, recursively, of every sub-jaxpr in its equations' params (scan
+    and loop bodies, pallas kernel bodies)."""
+    from jax.extend import core as jcore
+    acc = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            for v in eqn.outvars:
+                shape = getattr(getattr(v, "aval", None), "shape", None)
+                if shape is not None:
+                    acc.append((eqn.primitive.name, tuple(shape)))
+            for val in eqn.params.values():
+                if isinstance(val, jcore.ClosedJaxpr):
+                    walk(val.jaxpr)
+                elif isinstance(val, jcore.Jaxpr):
+                    walk(val)
+
+    walk(jaxpr)
+    return acc

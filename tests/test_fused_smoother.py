@@ -13,14 +13,15 @@ import repro.core  # noqa: F401  (enables x64)
 import jax
 import jax.numpy as jnp
 
-from helpers import spd_bcsr
+from helpers import jaxpr_outputs, spd_bcsr
 from repro.core import gamg
 from repro.core.vcycle import apply_smoother
 from repro.fem.assemble import assemble_elasticity
 from repro.kernels import backend
 from repro.kernels.fused_smoother import ops as fs_ops
 from repro.kernels.fused_smoother.fused_smoother import smoother_step_ell
-from repro.kernels.fused_smoother.ref import smoother_step_ref
+from repro.kernels.fused_smoother.ref import smoother_step_ref, \
+    smoother_step_seq
 
 RNG = np.random.default_rng(11)
 
@@ -47,9 +48,10 @@ def _operands(nbr=17, bs=3, k=None, dtype=np.float64):
 @pytest.mark.parametrize("k", [None, 3])
 @pytest.mark.parametrize("dtype", [np.float64, np.float32, jnp.bfloat16])
 def test_kernel_matches_reference(dtype, k):
-    """The tiled kernel vs the pure-jnp oracle, vector and panel RHS.
-    f64 must be bitwise (same per-row reduction order); low precision
-    at the family tolerance (tile padding perturbs rounding)."""
+    """The tiled kernel vs the pure-jnp oracle, vector and panel RHS, at
+    the family tolerance.  f64 must also be bitwise against the
+    separately written sequential reference (same per-row reduction
+    order)."""
     ell, dinv, b, x, d = _operands(k=k, dtype=dtype)
     nbr, bs = ell.nbr, ell.br
     coef = jnp.asarray([0.3, 0.7], ell.data.dtype)
@@ -58,15 +60,16 @@ def test_kernel_matches_reference(dtype, k):
             x.reshape(vshape), d.reshape(vshape), coef)
     acc = jnp.float32 if jnp.dtype(dtype) == jnp.bfloat16 else None
     xr, dr = smoother_step_ref(*args, accum_dtype=acc)
+    xs, ds = smoother_step_seq(*args, accum_dtype=acc)
     for tile in (4, 8, 32):
         xk, dk = smoother_step_ell(*args, tile_rows=tile, interpret=True,
                                    accum_dtype=acc)
         if jnp.dtype(dtype) == jnp.float64:
-            np.testing.assert_array_equal(np.asarray(xk), np.asarray(xr))
-            np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
-        else:
+            np.testing.assert_array_equal(np.asarray(xk), np.asarray(xs))
+            np.testing.assert_array_equal(np.asarray(dk), np.asarray(ds))
+        for got, want in ((xk, xr), (dk, dr)):
             np.testing.assert_allclose(
-                np.asarray(xk, np.float64), np.asarray(xr, np.float64),
+                np.asarray(got, np.float64), np.asarray(want, np.float64),
                 rtol=_tol(dtype), atol=_tol(dtype))
 
 
@@ -113,32 +116,20 @@ def test_fused_low_precision_tolerance(dtype):
 
 def test_fused_path_has_no_full_length_intermediates():
     """The point of the fusion: the fused jaxpr must contain neither the
-    full-length gathered-x array (nbr, kmax, bs) nor any full-length
-    residual subtraction — the kernel only ever touches (tile, ...)
-    slices, so r and z never exist at HBM size."""
+    full-length gathered-x array — row-major (nbr, kmax, bs) or lane-dense
+    (bs, kmax, nbr) — nor any full-length residual subtraction — the
+    kernel only ever touches (tile, ...) slices, so r and z never exist at
+    HBM size."""
     ell, dinv, b, x, d = _operands(nbr=32, bs=3)
     nbr, kmax, bs = ell.nbr, ell.kmax, ell.br
     tile = 8
     assert tile < nbr
 
-    def walk(jaxpr, acc):
-        for eqn in jaxpr.eqns:
-            for v in eqn.outvars:
-                aval = getattr(v, "aval", None)
-                if aval is not None and hasattr(aval, "shape"):
-                    acc.append((eqn.primitive.name, tuple(aval.shape)))
-            for val in eqn.params.values():
-                if isinstance(val, jax.core.ClosedJaxpr):
-                    walk(val.jaxpr, acc)
-                elif isinstance(val, jax.core.Jaxpr):
-                    walk(val, acc)
-        return acc
-
     fused = lambda bb, xx, dd: fs_ops.smoother_step(  # noqa: E731
         ell, dinv, bb, xx, dd, 0.3, 0.7, interpret=True, tile_rows=tile)
-    shapes = walk(jax.make_jaxpr(fused)(b, x, d).jaxpr, [])
-    full_gather = (nbr, kmax, bs)
-    assert full_gather not in [s for _, s in shapes], \
+    shapes = jaxpr_outputs(jax.make_jaxpr(fused)(b, x, d).jaxpr)
+    full_gathers = {(nbr, kmax, bs), (bs, kmax, nbr)}
+    assert not full_gathers & {s for _, s in shapes}, \
         "fused path materialized the full gathered-x array"
     full_subs = [s for p, s in shapes
                  if p == "sub" and s in ((nbr * bs,), (nbr, bs))]
@@ -150,8 +141,8 @@ def test_fused_path_has_no_full_length_intermediates():
     lv = LevelState(a_ell=ell, p_ell=ell, r_ell=None, dinv=dinv,
                     lam_max=jnp.asarray(2.0), p_t=None)
     unfused = lambda bb, xx: chebyshev_smooth(lv, bb, xx)  # noqa: E731
-    ushapes = walk(jax.make_jaxpr(unfused)(b, x).jaxpr, [])
-    assert full_gather in [s for _, s in ushapes], "oracle not sensitive"
+    ushapes = jaxpr_outputs(jax.make_jaxpr(unfused)(b, x).jaxpr)
+    assert full_gathers & {s for _, s in ushapes}, "oracle not sensitive"
     assert any(p == "sub" and s == (nbr * bs,) for p, s in ushapes)
 
 

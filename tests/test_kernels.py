@@ -193,3 +193,34 @@ def test_pbjacobi_sweep(nbr, bs, dtype, accum):
     assert got.dtype == dinv.dtype
     np.testing.assert_allclose(np.asarray(got, np.float64),
                                np.asarray(want, np.float64), **_tol(dtype))
+
+
+def test_compiled_f64_kernel_call_names_the_policy(monkeypatch):
+    """No compiled Pallas kernel takes an f64 payload (Mosaic has no 64-bit
+    floats): a front door asked for one refuses with the policy to use."""
+    from repro.kernels.block_spmv.ops import block_spmv
+    A = random_bcsr(RNG, 12, 12, 3, 3, density=0.3)
+    x = jnp.asarray(RNG.standard_normal(A.shape[1]))
+    with pytest.raises(ValueError, match="'f32' policy"):
+        block_spmv(A.to_ell(), x, interpret=False)
+    monkeypatch.setenv("REPRO_BACKEND", "tpu")      # compiled by default
+    with pytest.raises(ValueError, match="'f32' policy"):
+        spmv(A, x, use_kernel=True)
+
+
+def test_tpu_default_dispatch_keeps_f64_on_xla(monkeypatch):
+    """On a TPU the default path is the kernel for f32/bf16 payloads and
+    the XLA reference for f64 (the ``f64`` policy runs end to end on XLA)."""
+    from repro.kernels import backend
+    for knob in ("REPRO_SPGEMM_PATH", "REPRO_SPMM_PATH", "REPRO_SMOOTH_PATH"):
+        monkeypatch.delenv(knob, raising=False)
+    monkeypatch.setenv("REPRO_BACKEND", "tpu")
+    f64, f32 = np.dtype(np.float64), np.dtype(np.float32)
+    assert backend.resolve_use_kernel(None, f32)
+    assert not backend.resolve_use_kernel(None, f64)
+    assert backend.resolve_smooth_path(None, f32) == "fused"
+    assert backend.resolve_smooth_path(None, f64) == "reference"
+    assert backend.resolve_spmm_path(None, f32) == "kernel"
+    assert backend.resolve_spmm_path(None, f64) == "reference"
+    assert backend.resolve_spgemm_path(None, f32) == "fused"
+    assert backend.resolve_spgemm_path(None, f64) == "reference"
