@@ -167,35 +167,50 @@ def setup(A: BlockCSR, B: Array, *, theta: float = 0.08,
     if coarsener not in ("mis", "greedy"):
         raise ValueError(f"invalid coarsener {coarsener!r}: "
                          f"expected 'mis' or 'greedy'")
+
+    def phase(what):
+        # host span of one set-up phase of the level being built; it waits
+        # for the outputs it is handed, so its time is its own
+        return obs_trace.host_span(f"setup/level{len(levels)}/{what}")
+
     while Acur.nbr > coarse_size and len(levels) < max_levels - 1:
         bs = Acur.br
-        graph = strength_graph(Acur, theta)
-        if coarsener == "mis":
-            idx, mask = graph_to_ell(graph)
-            aggr = aggregation_from_device(mis_aggregate_device(idx, mask))
-            aggr = _repair_small_aggregates(aggr, graph,
-                                            min_size=-(-nns // bs))
-        else:
-            aggr = greedy_aggregate(graph, min_size=-(-nns // bs))
+        with phase("strength") as done:
+            graph = done(strength_graph(Acur, theta))
+        with phase("aggregate") as done:
+            if coarsener == "mis":
+                idx, mask = graph_to_ell(graph)
+                aggr = aggregation_from_device(
+                    mis_aggregate_device(idx, mask))
+                aggr = _repair_small_aggregates(aggr, graph,
+                                                min_size=-(-nns // bs))
+            else:
+                aggr = greedy_aggregate(graph, min_size=-(-nns // bs))
         if aggr.n_agg >= Acur.nbr:        # no coarsening possible
             break
-        Ptent, Bc = tentative_prolongator(aggr, Bcur, bs)
-        P, omega, lam, _plans = smoothed_prolongator(Acur, Ptent)
-        cache = ptap_symbolic(Acur, P)
+        with phase("tentative") as done:
+            Ptent, Bc = done(tentative_prolongator(aggr, Bcur, bs))
+        with phase("prolongator") as done:
+            P, omega, lam, _plans = done(smoothed_prolongator(Acur, Ptent))
+        with phase("ptap_symbolic") as done:
+            cache = done(ptap_symbolic(Acur, P))
         # each numeric phase is one program with its plan as arguments
         # (a TPU compiles every eager operation on its own)
-        a_next_data = jit_args.call(ptap_numeric_data, cache, Acur.data,
-                                    P.data)
+        with phase("ptap_numeric") as done:
+            a_next_data = done(jit_args.call(ptap_numeric_data, cache,
+                                             Acur.data, P.data))
         Anext = BlockCSR.from_arrays(cache.ac_plan.indptr,
                                      cache.ac_plan.indices, a_next_data,
                                      cache.n_coarse)
-        p_ell = jit_args.call(ELLPlan.build, P.ell_plan(), P.data)
-        if restriction == "stored":
-            R = transpose_bcsr(P)
-            r_ell, pt = R.to_ell(), None
-        else:
-            R, r_ell = None, None
-            pt = transpose_apply_plan(P, p_ell.kmax)
+        with phase("ell") as done:
+            p_ell = done(jit_args.call(ELLPlan.build, P.ell_plan(),
+                                       P.data))
+            if restriction == "stored":
+                R = transpose_bcsr(P)
+                r_ell, pt = done(R.to_ell()), None
+            else:
+                R, r_ell = None, None
+                pt = done(transpose_apply_plan(P, p_ell.kmax))
         levels.append(LevelSetup(
             A0=Acur, P=P, R=R, ptap_cache=cache,
             a_ell_plan=Acur.ell_plan(), p_ell=p_ell, r_ell=r_ell,
@@ -335,25 +350,26 @@ def recompute(setupd: GAMGSetup, a_fine_data: Array) -> Hierarchy:
     a_in = jnp.asarray(a_fine_data)
     states = []
     a_data = a_in.astype(h)
-    span = obs_trace.span
+    scope = obs_trace.scope
     for li, ls in enumerate(setupd.levels):
         # level-gated payload-corruption site (trace-time identity unless
         # a fault schedule is installed — repro.robust.inject)
         a_data = inject.maybe("hierarchy", a_data, level=li)
-        with span(f"recompute/level{li}/smoother_data"):
+        with scope(f"recompute/level{li}/smoother_data"):
             states.append(level_state(ls, a_data, policy))
-        with span(f"recompute/level{li}/ptap"):
+        with scope(f"recompute/level{li}/ptap"):
             a_data = ptap_numeric_data(ls.ptap_cache, a_data,
                                        ls.P.data.astype(h),
                                        accum_dtype=policy.kernel_accum_dtype)
     a_data = inject.maybe("hierarchy", a_data, level=len(setupd.levels))
     Ac = setupd.coarse_struct.with_data(a_data)
-    with span("recompute/coarse_chol"):
+    with scope("recompute/coarse_chol"):
         chol = coarse_cholesky(Ac.to_dense(), policy)
     a_fine_ell = None
     if policy.mixed and setupd.levels:
-        a_fine_ell = setupd.levels[0].a_ell_plan.build(
-            a_in.astype(policy.krylov_dtype))
+        with scope("recompute/fine_copy"):
+            a_fine_ell = setupd.levels[0].a_ell_plan.build(
+                a_in.astype(policy.krylov_dtype))
     return Hierarchy(levels=tuple(states), coarse_chol=chol,
                      a_fine_ell=a_fine_ell)
 
@@ -386,9 +402,15 @@ def _check_assembler(setupd: GAMGSetup, assembler) -> None:
             f"level has {nnzb}")
 
 
+def _assemble(assembler, E, nu):
+    """Device assembly of the fine operator's values, in its stage scope."""
+    with obs_trace.scope("recompute/assemble"):
+        return assembler.coo_data(E, nu)
+
+
 def _coeff_recompute(objs, E, nu):
     setupd, assembler = objs
-    return recompute(setupd, assembler.coo_data(E, nu))
+    return recompute(setupd, _assemble(assembler, E, nu))
 
 
 def make_coeff_recompute(setupd: GAMGSetup, assembler):
@@ -503,7 +525,7 @@ def make_coeff_solve(setupd: GAMGSetup, assembler, rtol: float = 1e-8,
     _check_assembler(setupd, assembler)
 
     def coeff_solve(E, nu, b, x0):
-        hier = recompute(setupd, assembler.coo_data(E, nu))
+        hier = recompute(setupd, _assemble(assembler, E, nu))
         return hier_solve(setupd, hier, b, x0, rtol=rtol,
                           maxiter=maxiter)
 
@@ -521,12 +543,14 @@ class GAMGSolver:
         # "obs" rides along to make_solve/make_block_solve (counters mode)
         solve_opts = {k: opts.pop(k) for k in ("rtol", "maxiter", "obs")
                       if k in opts}
-        self.setup_data = setup(A, B, **opts)
-        self._recompute = make_recompute(self.setup_data)
-        self._solve = make_solve(self.setup_data, **solve_opts)
-        self._solve_opts = solve_opts
-        self._solve_many = None
-        self.hierarchy = self._recompute(A.data)
+        with obs_trace.host_span("setup"):
+            self.setup_data = setup(A, B, **opts)
+            self._recompute = make_recompute(self.setup_data)
+            self._solve = make_solve(self.setup_data, **solve_opts)
+            self._solve_opts = solve_opts
+            self._solve_many = None
+            with obs_trace.host_span("setup/first_recompute") as done:
+                self.hierarchy = done(self._recompute(A.data))
         self.n_recomputes = 0
 
     def update_operator(self, a_fine_data: Array) -> None:
@@ -551,17 +575,20 @@ class GAMGSolver:
                 "update_coefficients needs a bound DeviceAssembler: "
                 "call bind_assembler(problem.assembler) (device assembly "
                 "path) first")
-        E, nu = self.assembler.as_fields(E, nu)
-        self.hierarchy = self._coeff_recompute(E, nu)
+        with obs_trace.host_span("update_coefficients"):
+            E, nu = self.assembler.as_fields(E, nu)
+            self.hierarchy = self._coeff_recompute(E, nu)
         self.n_recomputes += 1
 
     def solve(self, b: Array, x0: "Array | None" = None) -> CGResult:
         """Solve; ``x0`` warm-starts CG from a prior iterate (the
         time-march knob — pass the previous step's solution).  The cold
-        form keeps its own single jit cache entry."""
-        if x0 is None:
-            return self._solve(self.hierarchy, b)
-        return self._solve(self.hierarchy, b, x0)
+        form keeps its own single jit cache entry.  The host span
+        ``repro/solve`` covers the dispatch only: it waits for nothing."""
+        with obs_trace.host_span("solve"):
+            if x0 is None:
+                return self._solve(self.hierarchy, b)
+            return self._solve(self.hierarchy, b, x0)
 
     def solve_many(self, B: Array, x0: "Array | None" = None):
         """Panel solve: ``B (n, k)`` -> ``BlockCGResult`` (per-column

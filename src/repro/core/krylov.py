@@ -61,6 +61,16 @@ def wrap_precond(apply_m: Callable[[Array], Array], precond_dtype,
     return wrapped
 
 
+def in_scope(name: str, fn: Callable) -> Callable:
+    """``fn`` run inside the stage scope ``name`` (``obs_trace.scope``):
+    op metadata only, the numerics unchanged."""
+    def scoped(*args):
+        with obs_trace.scope(name):
+            return fn(*args)
+
+    return scoped
+
+
 def pcg(apply_a: Callable[[Array], Array],
         apply_m: Callable[[Array], Array],
         b: Array, x0: Array | None = None, rtol: float = 1e-8,
@@ -115,6 +125,10 @@ def pcg(apply_a: Callable[[Array], Array],
     ``counters`` field carries the totals.  ``tally=None`` (default)
     adds an *empty* pytree node to the carry — zero leaves, zero jaxpr
     residue, the recurrence bitwise unchanged (``tests/test_obs.py``).
+
+    Stage scopes: every operator application runs inside ``pcg/apply_a``
+    and every preconditioner application, its precision casts included,
+    inside ``pcg/precond``; the dots and updates are in neither.
     """
     counted = tally is not None
     if counted:
@@ -122,6 +136,8 @@ def pcg(apply_a: Callable[[Array], Array],
                                                   b.dtype)
     else:
         apply_m = wrap_precond(apply_m, precond_dtype, b.dtype)
+    apply_a = in_scope("pcg/apply_a", apply_a)
+    apply_m = in_scope("pcg/precond", apply_m)
     x = jnp.zeros_like(b) if x0 is None else x0
     r = b - apply_a(x)
     if counted:

@@ -74,7 +74,7 @@ def spmv_ell(ell: BlockELL, x: Array) -> Array:
 
     Padded slots point at column 0 with exactly-zero data blocks, so they
     contribute nothing."""
-    with obs_trace.span("spmv_ell"):
+    with obs_trace.scope("spmv_ell"):
         xb = x.reshape(ell.nbc, ell.bc)
         return ell_contract(ell.data, xb, ell.indices).reshape(
             ell.nbr * ell.br)
@@ -88,7 +88,7 @@ def spmm_ell(ell: BlockELL, X: Array) -> Array:
     *bitwise* the single-RHS result (same reduction graph) — the multi-RHS
     layer's k=1 exactness contract rests on this.
     """
-    with obs_trace.span("spmm_ell"):
+    with obs_trace.scope("spmm_ell"):
         m = X.shape[1]
         if m == 1:
             return spmv_ell(ell, X[:, 0])[:, None]
@@ -110,7 +110,7 @@ def apply_ell_t(ell: BlockELL, pt: EllTransposePlan, x: Array) -> Array:
     the same order.  Panel-polymorphic like ``apply_ell``: ``x`` is
     ``(nbr*br,)`` or ``(nbr*br, k)``.
     """
-    with obs_trace.span("apply_ell_t"):
+    with obs_trace.scope("apply_ell_t"):
         nbr, kmax, br, bc = ell.data.shape
         flat = to_lanes(ell.data.reshape(nbr * kmax, br, bc))
         mask_t = jnp.asarray(pt.mask).T

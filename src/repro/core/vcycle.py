@@ -244,16 +244,17 @@ def vcycle(hier: Hierarchy, b: Array, smoother: str = "chebyshev",
     panel cycle is per-column identical to k single cycles (tested in
     ``tests/test_multirhs.py``).
 
-    Observability (ISSUE 7, all governed by ``REPRO_OBS``): every stage
-    runs inside a named scope (``vcycle/level{i}/smooth|restrict|prolong``
-    and ``vcycle/coarse``) so a profiler capture reads as a per-level
-    timeline; with a ``tally`` (a ``repro.obs.trace.CycleTally``) the
+    Observability: every stage runs inside an always-on named
+    scope (``vcycle/level{i}/smooth|residual|restrict|prolong`` and
+    ``vcycle/coarse``, ``repro.obs.trace.scope``) so a profiler capture
+    reads as a per-level timeline; with a ``tally`` (a
+    ``repro.obs.trace.CycleTally``, ``REPRO_OBS=counters``) the
     cycle additionally returns ``(x, tally')`` with level visits, smoother
     applications and the coarse solve counted on device.  ``tally=None``
     (the default) leaves both signature and jaxpr exactly the pre-obs
     ones — zero residue, pinned by ``tests/test_obs.py``.
     """
-    span = obs_trace.span
+    scope = obs_trace.scope
     counted = tally is not None
     bs_stack = []
     x_stack = []
@@ -262,21 +263,22 @@ def vcycle(hier: Hierarchy, b: Array, smoother: str = "chebyshev",
         tally = tally._replace(
             precond_applies=tally.precond_applies + 1)
     for li, lv in enumerate(hier.levels):
-        with span(f"vcycle/level{li}/smooth"):
+        with scope(f"vcycle/level{li}/smooth"):
             x = apply_smoother(lv, rhs, jnp.zeros_like(rhs), smoother,
                                degree)
-        r = rhs - apply_ell(lv.a_ell, x)
+        with scope(f"vcycle/level{li}/residual"):
+            r = rhs - apply_ell(lv.a_ell, x)
         bs_stack.append(rhs)
         x_stack.append(x)
         # restrict; inject.maybe is a trace-time identity unless a fault
         # schedule is installed (repro.robust.inject)
-        with span(f"vcycle/level{li}/restrict"):
+        with scope(f"vcycle/level{li}/restrict"):
             rhs = inject.maybe("vcycle", apply_restriction(lv, r), level=li)
         if counted:
             tally = tally._replace(
                 level_visits=tally.level_visits.at[li].add(1),
                 smoother_applies=tally.smoother_applies.at[li].add(1))
-    with span("vcycle/coarse"):
+    with scope("vcycle/coarse"):
         xc = inject.maybe(
             "coarse",
             jax.scipy.linalg.cho_solve((hier.coarse_chol, True), rhs))
@@ -287,9 +289,9 @@ def vcycle(hier: Hierarchy, b: Array, smoother: str = "chebyshev",
                                             reversed(bs_stack),
                                             reversed(x_stack))):
         li = nlev - 1 - up
-        with span(f"vcycle/level{li}/prolong"):
+        with scope(f"vcycle/level{li}/prolong"):
             x = x + apply_ell(lv.p_ell, xc)       # prolong + correct
-        with span(f"vcycle/level{li}/smooth"):
+        with scope(f"vcycle/level{li}/smooth"):
             xc = apply_smoother(lv, rhs_l, x, smoother, degree)
         if counted:
             tally = tally._replace(

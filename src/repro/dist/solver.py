@@ -1104,11 +1104,11 @@ def make_dist_solver(dg: DistGAMG, setupd: GAMGSetup, mesh, *,
         # consumed at trace time, like the kernel path knobs: every rank
         # traces the same Python, so the schedule choice is collective-safe
         overlap = resolve_overlap() == "on"
-        # metadata-only spans: identical on every rank, collective-safe
-        with obs_trace.span("dist/recompute"):
+        # metadata-only stage scopes: identical on every rank, collective-safe
+        with obs_trace.scope("dist/recompute"):
             states, chol = _rank_recompute(dg, args, a0, overlap)
         run_pcg = _rank_block_pcg if b.ndim == 3 else _rank_pcg
-        with obs_trace.span("dist/pcg"):
+        with obs_trace.scope("dist/pcg"):
             x, k, relres, ok, status = run_pcg(dg, args, states, chol, b,
                                                rtol, maxiter, overlap,
                                                x0=x0)
@@ -1128,7 +1128,7 @@ def make_dist_solver(dg: DistGAMG, setupd: GAMGSetup, mesh, *,
 
     sharded = jax.shard_map(rank_fn, mesh=mesh, in_specs=in_specs,
                             out_specs=P(AXIS), check_vma=False)
-    return _with_rank0_span(jax.jit(sharded), "dist/solve")
+    return jax.jit(sharded)
 
 
 def make_dist_coeff_solver(dg: DistGAMG, da: DistAssembly, mesh, *,
@@ -1154,12 +1154,12 @@ def make_dist_coeff_solver(dg: DistGAMG, da: DistAssembly, mesh, *,
 
     def rank_body(args, aargs, E, nu, b, x0):
         overlap = resolve_overlap() == "on"
-        with obs_trace.span("dist/assemble"):
+        with obs_trace.scope("dist/assemble"):
             a_slab = _rank_assemble(da, aargs, E, nu)
-        with obs_trace.span("dist/recompute"):
+        with obs_trace.scope("dist/recompute"):
             states, chol = _rank_recompute(dg, args, a_slab, overlap)
         run_pcg = _rank_block_pcg if b.ndim == 3 else _rank_pcg
-        with obs_trace.span("dist/pcg"):
+        with obs_trace.scope("dist/pcg"):
             x, k, relres, ok, status = run_pcg(dg, args, states, chol, b,
                                                rtol, maxiter, overlap,
                                                x0=x0)
@@ -1180,24 +1180,6 @@ def make_dist_coeff_solver(dg: DistGAMG, da: DistAssembly, mesh, *,
 
     sharded = jax.shard_map(rank_fn, mesh=mesh, in_specs=in_specs,
                             out_specs=P(AXIS), check_vma=False)
-    return _with_rank0_span(jax.jit(sharded), "dist/coeff_solve")
+    return jax.jit(sharded)
 
 
-def _with_rank0_span(jitted, name: str):
-    """Wrap a jitted dist entry point in a rank-0 host timing span.
-
-    Resolved at *build* time like every other obs decision: with spans off
-    (the default) the jitted callable is returned untouched — zero wrapper,
-    zero overhead.  Enabled, each call lands one blocked wall-clock
-    observation in the default registry's ``{name}/seconds`` histogram,
-    recorded only on process rank 0 (``obs_trace.rank0_span``) so
-    multi-process runs stay collective-safe.
-    """
-    if not obs_trace.spans_enabled():
-        return jitted
-
-    def timed(*args):
-        with obs_trace.rank0_span(name) as stop:
-            return stop(jitted(*args))
-
-    return timed

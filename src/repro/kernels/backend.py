@@ -41,8 +41,8 @@ interpreter.  This module centralizes the decision:
                            default off (``None``).
 * ``resolve_obs``        — the observability mode (``repro.obs``):
                            ``None`` falls back to ``REPRO_OBS``
-                           ("off" | "spans" | "counters"), default off —
-                           the zero-jaxpr-residue contract.
+                           ("off" | "counters"), default off — the
+                           zero-jaxpr-residue contract.
 * ``resolve_smooth_path``— V-cycle smoother execution path: the fused
                            Pallas recurrence step (``repro.kernels.
                            fused_smoother``) on TPU, the unfused jnp
@@ -314,17 +314,16 @@ def resolve_faults(spec=None):
 def resolve_obs(mode=None) -> str:
     """Default observability mode; honours the ``REPRO_OBS`` knob.
 
-    "off"       (default) no spans, no counters — monitored hot paths are
-                bitwise the unmonitored ones with zero jaxpr residue.
-    "spans"     ``jax.named_scope``/``TraceAnnotation`` wrappers on every
-                kernel family and V-cycle stage (metadata only, numerics
-                unchanged).
-    "counters"  spans plus the device-side ``CycleTally`` carry threaded
-                through ``pcg``/``block_pcg``/``vcycle``.
+    "off"       (default) no counters — monitored hot paths are bitwise
+                the unmonitored ones with zero jaxpr residue.
+    "counters"  the device-side ``CycleTally`` carry threaded through
+                ``pcg``/``block_pcg``/``vcycle``.
 
-    Re-read per call (mirroring the path knobs); like them, the mode is
-    consumed at *trace* time, so it must be set before the solver under
-    observation is built.  Invalid values raise ``ValueError``.
+    The stage scopes (``repro.obs.trace.scope``) are op metadata and
+    always on; no mode governs them.  Re-read per call (mirroring the
+    path knobs); like them, the mode is consumed at *trace* time, so it
+    must be set before the solver under observation is built.  Invalid
+    values raise ``ValueError``.
     """
     if mode is None:
         mode = os.environ.get("REPRO_OBS")
@@ -333,12 +332,10 @@ def resolve_obs(mode=None) -> str:
     key = str(mode).strip().lower()
     if key in ("", "0", "off", "false", "none"):
         return "off"
-    if key in ("1", "on", "true", "spans"):
-        return "spans"
     if key == "counters":
         return "counters"
     raise ValueError(
-        f"invalid observability mode {mode!r}: expected 'off', 'spans' or "
+        f"invalid observability mode {mode!r}: expected 'off' or "
         f"'counters' (from REPRO_OBS or the obs= knob)")
 
 

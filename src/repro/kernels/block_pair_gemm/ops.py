@@ -9,12 +9,12 @@ __all__ = ["block_pair_gemm"]
 
 
 def block_pair_gemm(lhs, rhs, *, interpret: bool | None = None, **kwargs):
-    """Front door with the observability span (trace-time no-op when off).
+    """Front door inside the ``kernels/block_pair_gemm`` stage scope.
 
     ``interpret=None`` compiles on TPU and interprets elsewhere
     (``backend.kernel_interpret``, which refuses a compiled f64 call).
     """
-    with obs_trace.span("kernels/block_pair_gemm"):
+    with obs_trace.scope("kernels/block_pair_gemm"):
         interpret = backend.kernel_interpret(interpret, lhs.dtype,
                                              "block_pair_gemm")
         return _block_pair_gemm(lhs, rhs, interpret=interpret, **kwargs)

@@ -27,7 +27,7 @@ def block_seg_sum(vals: jax.Array, seg_ids: jax.Array, num_segments: int,
     per-segment results round back to ``vals.dtype``.  ``interpret=None``
     compiles on TPU and interprets elsewhere (``backend.kernel_interpret``).
     """
-    with obs_trace.span("kernels/block_seg_sum"):
+    with obs_trace.scope("kernels/block_seg_sum"):
         interpret = backend.kernel_interpret(interpret, vals.dtype,
                                              "block_seg_sum")
         return _block_seg_sum(vals, seg_ids, num_segments,

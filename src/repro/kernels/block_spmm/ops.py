@@ -28,7 +28,7 @@ def block_spmm(ell: BlockELL, X: jax.Array, *, interpret: bool | None = None,
     cached winner the lane tile comes from the VMEM budget and the panel
     pads to 8 columns).
     """
-    with obs_trace.span("kernels/block_spmm"):
+    with obs_trace.scope("kernels/block_spmm"):
         interpret = backend.kernel_interpret(interpret, ell.data.dtype,
                                              "block_spmm")
         k = X.shape[1]

@@ -20,7 +20,7 @@ def block_spmv(ell: BlockELL, x: jax.Array, *, interpret: bool | None = None,
     (``repro.kernels.autotune``, governed by ``REPRO_TUNE``; without a
     cached winner the kernel sizes its lane tile from the VMEM budget).
     """
-    with obs_trace.span("kernels/block_spmv"):
+    with obs_trace.scope("kernels/block_spmv"):
         interpret = backend.kernel_interpret(interpret, ell.data.dtype,
                                              "block_spmv")
         if tile_rows is None:

@@ -19,8 +19,8 @@ def fused_pair_gemm_lanes(lhs: jax.Array, rhs: jax.Array, *,
                           interpret: bool | None = None,
                           accum_dtype=None) -> jax.Array:
     """Front door on lane-dense operands ``(br, bk, kmax, nslots)`` and
-    ``(bk, bc, kmax, nslots)`` -> ``(br, bc, nslots)``, with the
-    observability span (trace-time no-op when off).
+    ``(bk, bc, kmax, nslots)`` -> ``(br, bc, nslots)``, inside the
+    ``kernels/fused_pair_gemm`` stage scope.
 
     ``interpret=None`` compiles on TPU and interprets elsewhere
     (``backend.kernel_interpret``, which refuses a compiled f64 call).
@@ -28,7 +28,7 @@ def fused_pair_gemm_lanes(lhs: jax.Array, rhs: jax.Array, *,
     (``repro.kernels.autotune``, governed by ``REPRO_TUNE``); no cached
     winner falls back to the kernel's VMEM-budget ``default_tile_slots``.
     """
-    with obs_trace.span("kernels/fused_pair_gemm"):
+    with obs_trace.scope("kernels/fused_pair_gemm"):
         interpret = backend.kernel_interpret(interpret, lhs.dtype,
                                              "fused_pair_gemm")
         if tile_slots is None:

@@ -31,7 +31,7 @@ def smoother_step(a_ell: BlockELL, dinv: jax.Array, b: jax.Array,
     (``repro.kernels.autotune``, governed by ``REPRO_TUNE``; without a
     cached winner the lane tile comes from the VMEM budget).
     """
-    with obs_trace.span("kernels/fused_smoother"):
+    with obs_trace.scope("kernels/fused_smoother"):
         interpret = backend.kernel_interpret(interpret, a_ell.data.dtype,
                                              "fused_smoother")
         nbr, kmax, bs, _ = a_ell.data.shape

@@ -21,7 +21,7 @@ def pbjacobi_apply(dinv: jax.Array, r: jax.Array, x: jax.Array, omega,
     (``repro.kernels.autotune``, governed by ``REPRO_TUNE``; without a
     cached winner the lane tile comes from the VMEM budget).
     """
-    with obs_trace.span("kernels/pbjacobi"):
+    with obs_trace.scope("kernels/pbjacobi"):
         interpret = backend.kernel_interpret(interpret, dinv.dtype,
                                              "pbjacobi")
         nbr, bs, _ = dinv.shape

@@ -28,7 +28,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.krylov import wrap_precond
+from repro.core.krylov import in_scope, wrap_precond
 from repro.core.vcycle import Hierarchy, fine_operator, vcycle
 from repro.core.spmv import apply_ell
 from repro.obs import trace as obs_trace
@@ -125,6 +125,9 @@ def block_pcg(apply_a: Callable[[Array], Array],
                                                   B.dtype)
     else:
         apply_m = wrap_precond(apply_m, precond_dtype, B.dtype)
+    # the stage scopes of ``pcg``: operator, preconditioner (casts included)
+    apply_a = in_scope("pcg/apply_a", apply_a)
+    apply_m = in_scope("pcg/precond", apply_m)
     x = jnp.zeros_like(B) if x0 is None else x0
     r = B - apply_a(x)
     if counted:

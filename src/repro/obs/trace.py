@@ -1,52 +1,64 @@
-"""Device-resident tracing: named scopes + the solve counter carry.
+"""Stage scopes, host spans and the solve counter carry.
 
-The device half of the observability layer (ISSUE 7).  Two opt-in
-mechanisms, gated by one knob (``REPRO_OBS``, resolved by
-``repro.kernels.backend.resolve_obs``):
+The device half of the observability layer.  Three mechanisms:
 
-``"spans"``     every kernel family (``block_spmv``, ``block_spmm``,
-                ``pbjacobi``, ``fused_pair_gemm``, the pair/seg SpGEMM
-                stages) and every V-cycle stage
-                (``vcycle/level{i}/smooth|restrict|prolong``, ``coarse``)
-                runs inside a ``jax.named_scope`` + profiler
-                ``TraceAnnotation``, so a ``jax.profiler.trace`` capture
-                reads as a legible per-level timeline instead of a wall
-                of fused HLO.  Scopes are metadata only: the lowered
-                computation is numerically identical, pinned bitwise by
-                ``tests/test_obs.py``.
+``scope(name)``      a ``jax.named_scope`` around one device stage, always
+                     on: every kernel family (``kernels/block_spmv``,
+                     ``kernels/fused_smoother``, ...), the outer Krylov
+                     stages (``pcg/apply_a``, ``pcg/precond``), every
+                     V-cycle stage (``vcycle/level{i}/smooth|residual|
+                     restrict|prolong``, ``vcycle/coarse``), every recompute
+                     stage (``recompute/assemble``, ``recompute/level{i}/
+                     smoother_data|ptap``, ``recompute/coarse_chol``,
+                     ``recompute/fine_copy``) and the distributed stages
+                     (``dist/assemble|recompute|pcg``).  A scope is op
+                     metadata only: it lands in every HLO instruction's
+                     ``op_name`` (the profiler's ``tf_op`` stat), costs
+                     nothing at run time and leaves the numerics bitwise
+                     unchanged (``tests/test_obs.py``).
 
-``"counters"``  spans *plus* a device-side ``CycleTally`` threaded
-                through the ``pcg``/``block_pcg``/``vcycle`` carries:
-                per-level visit counts, smoother applications, coarse
-                solves, operator/preconditioner applications, and the
-                modeled HBM bytes of the cycle
-                (``repro.obs.model.vcycle_traffic``) multiplied in — so
-                a converged ``CGResult.counters`` states exactly what the
-                solve did and what it should have cost.
+``host_span(name)``  a ``jax.profiler.TraceAnnotation`` named
+                     ``repro/<name>`` around one host stage (set-up phases,
+                     ``GAMGSolver.solve``, ``update_coefficients``), on the
+                     device trace's clock, plus an in-memory ``HostSpan``
+                     record (``host_spans()``) that also holds the backend
+                     compile and persistent-cache load seconds that fell
+                     inside it.  It blocks on nothing unless handed outputs
+                     to wait for.
 
-``"off"``       (default) both mechanisms vanish **at trace time**: the
-                ``span`` helper returns a null context and no tally is
-                threaded, so the jaxpr carries zero residue and nothing
-                retraces — the same contract ``repro.robust.inject``
-                pins for the fault hooks.
+``CycleTally``       the opt-in device-side counters, threaded through the
+                     ``pcg``/``block_pcg``/``vcycle`` carries under
+                     ``REPRO_OBS=counters``: per-level visit counts,
+                     smoother applications, coarse solves,
+                     operator/preconditioner applications and the modeled
+                     HBM bytes of the cycle (``repro.obs.model.
+                     vcycle_traffic``).  The tally changes the program (an
+                     extra carry), so it is read at *trace* time and
+                     ``REPRO_OBS=off`` (the default) leaves zero jaxpr
+                     residue.  Set ``REPRO_OBS`` (or enter ``use(...)``)
+                     before building the solver under observation.
 
-Mode is read at *trace* time (like the kernel-path knobs): programs
-jitted while the mode was ``off`` keep their clean traces even if the
-mode is flipped later — set ``REPRO_OBS`` (or enter ``use(...)``) before
-building the solver under observation.
+The persistent compilation cache leaves op metadata out of its key
+(``jax_compilation_cache_include_metadata_in_key`` is false), so an
+executable cached before a scope was added or renamed is loaded without
+it: clear ``.jax_cache`` after renaming a scope.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import dataclasses
+import threading
 import time
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import monitoring
 
 Array = jax.Array
 
-MODES = ("off", "spans", "counters")
+MODES = ("off", "counters")
 
 #: Explicit override (``use`` context manager); ``None`` defers to the
 #: ``REPRO_OBS`` env knob via ``backend.resolve_obs``.
@@ -61,10 +73,6 @@ def resolve(mode: Optional[str] = None) -> str:
     if _MODE is not None:
         return _MODE
     return backend.resolve_obs()
-
-
-def spans_enabled(mode: Optional[str] = None) -> bool:
-    return resolve(mode) in ("spans", "counters")
 
 
 def counters_enabled(mode: Optional[str] = None) -> bool:
@@ -88,25 +96,107 @@ def use(mode: str):
         _MODE = prev
 
 
-def span(name: str, mode: Optional[str] = None):
-    """Named scope around one solver stage (trace-time no-op when off).
+def scope(name: str):
+    """Named scope around one device stage: ``jax.named_scope(name)``.
 
-    Inside a traced program this nests the stage under ``name`` in the
-    XLA metadata/name stack, which is what ``jax.profiler`` renders as
-    the per-level timeline; outside a trace it additionally opens a
-    profiler ``TraceAnnotation`` so eager stages show up too.  With the
-    mode off it returns a null context — zero jaxpr residue, nothing to
-    retrace.
-    """
-    if not spans_enabled(mode):
-        return contextlib.nullcontext()
-    ctx = contextlib.ExitStack()
-    ctx.enter_context(jax.named_scope(name))
+    Nests the stage under ``name`` in every HLO instruction's ``op_name``
+    metadata, which a profiler trace carries as each op's ``tf_op``."""
+    return jax.named_scope(name)
+
+
+# ---------------------------------------------------------------------------
+# Host spans
+# ---------------------------------------------------------------------------
+
+#: Span prefix on the profiler's clock: ``repro/<name>``.
+HOST_PREFIX = "repro/"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: Records kept; the oldest are dropped first in a long-running process.
+MAX_RECORDS = 100_000
+
+
+@dataclasses.dataclass
+class HostSpan:
+    """One closed host span."""
+
+    name: str                 # without the ``repro/`` prefix
+    parent: Optional[str]     # the enclosing host span's name, if any
+    start: float              # ``time.perf_counter()`` seconds
+    end: float
+    count: int                # how often ``name`` was opened, this one included
+    compile_s: float = 0.0    # backend compile + cache-load seconds inside
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+_RECORDS: "collections.deque[HostSpan]" = collections.deque(
+    maxlen=MAX_RECORDS)
+_COUNTS: "collections.Counter[str]" = collections.Counter()
+_COUNTS_LOCK = threading.Lock()
+_OPEN = threading.local()          # each thread's stack of open spans
+
+
+def _open_spans() -> List[HostSpan]:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == COMPILE_EVENT:
+        for rec in _open_spans():
+            rec.compile_s += duration
+
+
+monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+@contextlib.contextmanager
+def host_span(name: str):
+    """Host span ``repro/<name>`` around one host stage.
+
+    Yields ``wait(out) -> out``: outputs handed to it are waited for
+    before the span closes, so an asynchronously dispatched stage's time
+    is its own; a span given nothing blocks on nothing.  The span is a
+    profiler ``TraceAnnotation`` only, never a ``named_scope``, so a
+    program first traced inside it carries no trace of the call site.
+    A span that raises is not recorded."""
+    stack = _open_spans()
+    with _COUNTS_LOCK:
+        _COUNTS[name] += 1
+        count = _COUNTS[name]
+    rec = HostSpan(name=name, parent=stack[-1].name if stack else None,
+                   start=time.perf_counter(), end=float("nan"), count=count)
+    outs = []
+
+    def wait(out):
+        outs.append(out)
+        return out
+
+    stack.append(rec)
     try:
-        ctx.enter_context(jax.profiler.TraceAnnotation(name))
-    except Exception:  # pragma: no cover - profiler backend missing
-        pass
-    return ctx
+        with jax.profiler.TraceAnnotation(HOST_PREFIX + name):
+            yield wait
+            if outs:
+                jax.block_until_ready(outs)
+    finally:
+        stack.pop()
+    rec.end = time.perf_counter()
+    _RECORDS.append(rec)
+
+
+def host_spans() -> List[HostSpan]:
+    """The closed host spans of this process, oldest first."""
+    return list(_RECORDS)
+
+
+def reset_host_spans() -> None:
+    _RECORDS.clear()
+    with _COUNTS_LOCK:
+        _COUNTS.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -163,43 +253,6 @@ def describe_tally(tally: CycleTally) -> str:
             f"coarse={int(tally.coarse_solves)} "
             f"level_visits={lv.tolist()} smoother={sm.tolist()} "
             f"modeled_MB={float(tally.modeled_bytes) / 1e6:.2f}")
-
-
-# ---------------------------------------------------------------------------
-# Host-side spans for the distributed path
-# ---------------------------------------------------------------------------
-
-@contextlib.contextmanager
-def rank0_span(name: str, registry=None):
-    """Host-side timing span emitted only on process rank 0.
-
-    The dist solvers run inside ``shard_map`` where per-rank host work
-    would desynchronize collectives; this span therefore wraps the
-    *call site* (staging, the jitted shard_map invocation) on the host,
-    and only rank 0 records — every other process runs the identical
-    code path with recording skipped, so multi-process runs stay
-    collective-safe by construction.  Always yields a ``stop(out)``
-    callable that blocks on device output before the clock stops.
-    """
-    emit = jax.process_index() == 0 and spans_enabled()
-    state = {"out": None}
-
-    def stop(out):
-        state["out"] = out
-        return out
-
-    t0 = time.perf_counter()
-    try:
-        yield stop
-    finally:
-        if emit:
-            from repro.obs.metrics import block_ready, default_registry
-            if state["out"] is not None:
-                block_ready(state["out"])
-            dt = time.perf_counter() - t0
-            reg = registry if registry is not None else default_registry()
-            reg.histogram(f"{name}/seconds",
-                          help="rank-0 host span").observe(dt)
 
 
 def wrap_threaded_precond(apply_m: Callable, precond_dtype,

@@ -2,12 +2,15 @@
 
 What this module pins:
 
-* ``REPRO_OBS=off`` is FREE: span wrappers and the counter plumbing leave
-  **zero jaxpr residue** (the off-mode trace is byte-identical before and
-  after an obs scope), and spans mode is **bitwise** the off-mode solve
-  (named scopes are metadata only);
-* zero retraces: a spans-mode ``GAMGSolver``'s jitted closures keep their
-  cache at 1 across repeated solves;
+* ``REPRO_OBS=off|counters`` resolution, and ``off`` is FREE: the
+  counter plumbing leaves **zero jaxpr residue** (the off-mode trace is
+  byte-identical before and after a counters scope);
+* the always-on stage scopes are op metadata: the compiled solve and
+  coefficient-recompute programs carry them in their ``op_name``s, and
+  the solution is **bitwise** that of a build without them, with zero
+  retraces;
+* host spans: records with parent, count and the compile seconds that
+  fell inside, and no name-stack entry in a program traced inside one;
 * counter correctness: on a pinned 2-level problem the ``CycleTally``
   matches the analytic expectations of AMG-preconditioned CG exactly
   (one V-cycle per operator application, two smoother sweeps per visited
@@ -21,6 +24,7 @@ What this module pins:
 * ``AMGSolveServer`` end-to-end metrics: queue wait / latency / solve
   wall histograms, padding efficiency, per-bucket and per-status counts.
 """
+import contextlib
 import json
 import math
 
@@ -70,12 +74,13 @@ def hier(setupd, prob):
 
 def test_resolve_obs_knob(monkeypatch):
     for raw, want in (("off", "off"), ("0", "off"), ("", "off"),
-                      ("none", "off"), ("spans", "spans"), ("1", "spans"),
-                      ("ON", "spans"), ("counters", "counters"),
-                      ("Counters", "counters")):
+                      ("none", "off"), ("False", "off"),
+                      ("counters", "counters"), ("Counters", "counters")):
         assert resolve_obs(raw) == want
-    with pytest.raises(ValueError, match="invalid observability mode"):
-        resolve_obs("verbose")
+    # the stage scopes are always on: no mode switches them
+    for raw in ("verbose", "spans", "on", "1"):
+        with pytest.raises(ValueError, match="invalid observability mode"):
+            resolve_obs(raw)
     monkeypatch.delenv("REPRO_OBS", raising=False)
     assert resolve_obs() == "off"
     monkeypatch.setenv("REPRO_OBS", "counters")
@@ -86,15 +91,16 @@ def test_resolve_obs_knob(monkeypatch):
 def test_use_scope_overrides_env(monkeypatch):
     monkeypatch.delenv("REPRO_OBS", raising=False)
     assert obs_trace.resolve() == "off"
+    assert not obs_trace.counters_enabled()
     with obs_trace.use("counters"):
         assert obs_trace.resolve() == "counters"
         assert obs_trace.counters_enabled()
-        assert obs_trace.spans_enabled()
         # explicit arg still wins over the scope
-        assert obs_trace.resolve("spans") == "spans"
+        assert obs_trace.resolve("off") == "off"
+        assert not obs_trace.counters_enabled("off")
     assert obs_trace.resolve() == "off"
     with pytest.raises(ValueError):
-        obs_trace.use("loud").__enter__()
+        obs_trace.use("spans").__enter__()
 
 
 # ---------------------------------------------------------------------------
@@ -124,21 +130,57 @@ def test_off_mode_zero_jaxpr_residue(setupd, hier, prob):
     assert before != during, "counters mode must thread the tally carry"
 
 
-def test_spans_mode_bitwise_matches_off(setupd, hier, prob):
-    """Named scopes are metadata: the spans-mode solve is bitwise the
-    off-mode solve — same solution, same iteration count, same relres."""
+def _null_scope(name):
+    return contextlib.nullcontext()
+
+
+def _compiled_text(fn, *args) -> str:
+    return fn.lower(*args).compile().as_text()
+
+
+def test_spans_mode_bitwise_matches_off(setupd, hier, prob, monkeypatch):
+    """The always-on stage scopes are metadata: the solve is bitwise that
+    of a build with ``scope`` replaced by a null context — same solution,
+    same iteration count, same relres — and neither retraces."""
     b = jnp.asarray(prob.b)
-    res_off = gamg.make_solve(setupd, rtol=1e-8, maxiter=100,
-                              obs="off")(hier, b)
-    res_spans = gamg.make_solve(setupd, rtol=1e-8, maxiter=100,
-                                obs="spans")(hier, b)
-    assert bool(res_off.converged) and bool(res_spans.converged)
-    np.testing.assert_array_equal(np.asarray(res_off.x),
-                                  np.asarray(res_spans.x))
-    assert int(res_off.iters) == int(res_spans.iters)
-    np.testing.assert_array_equal(np.asarray(res_off.relres),
-                                  np.asarray(res_spans.relres))
-    assert res_off.counters is None and res_spans.counters is None
+    scoped = gamg.make_solve(setupd, rtol=1e-8, maxiter=100)
+    res_scoped = scoped(hier, b)
+    with monkeypatch.context() as mp:
+        mp.setattr(obs_trace, "scope", _null_scope)
+        bare = gamg.make_solve(setupd, rtol=1e-8, maxiter=100)
+        res_bare = bare(hier, b)
+        assert "pcg/precond" not in _compiled_text(bare, hier, b)
+    assert "pcg/precond" in _compiled_text(scoped, hier, b)
+    assert bool(res_scoped.converged) and bool(res_bare.converged)
+    np.testing.assert_array_equal(np.asarray(res_scoped.x),
+                                  np.asarray(res_bare.x))
+    assert int(res_scoped.iters) == int(res_bare.iters)
+    np.testing.assert_array_equal(np.asarray(res_scoped.relres),
+                                  np.asarray(res_bare.relres))
+    assert res_scoped.counters is None and res_bare.counters is None
+    scoped(hier, 2.0 * b)
+    assert scoped._cache_size() == 1 and bare._cache_size() == 1
+
+
+def test_make_solve_hlo_carries_stage_scopes(setupd, hier, prob):
+    """The compiled solve names its stages in its ops' ``op_name``s, the
+    metadata a profiler trace reports as each op's ``tf_op``."""
+    solve = gamg.make_solve(setupd, rtol=1e-8, maxiter=100)
+    text = _compiled_text(solve, hier, jnp.asarray(prob.b))
+    for stage in ("pcg/precond/vcycle/level0/smooth", "pcg/apply_a",
+                  "vcycle/level0/residual", "vcycle/level0/restrict",
+                  "vcycle/level0/prolong", "vcycle/coarse"):
+        assert stage in text, stage
+
+
+def test_coeff_recompute_hlo_carries_stage_scopes(setupd, prob):
+    E, nu = prob.assembler.as_fields(1.0, 0.3)
+    prog = gamg.make_coeff_recompute(setupd, prob.assembler)
+    text = _compiled_text(prog, E, nu)
+    for stage in ("recompute/assemble", "recompute/level0/ptap",
+                  "recompute/level0/smoother_data",
+                  "recompute/coarse_chol"):
+        assert stage in text, stage
 
 
 def test_counters_mode_matches_off_solution(setupd, hier, prob):
@@ -154,13 +196,13 @@ def test_counters_mode_matches_off_solution(setupd, hier, prob):
 
 
 def test_spans_solver_cache_stays_at_one(prob):
-    """Zero retraces across repeated solves under span wrappers."""
-    with obs_trace.use("spans"):
-        solver = gamg.GAMGSolver(prob.A, prob.B, coarse_size=40,
-                                 rtol=1e-8, maxiter=100, precision="f64")
-        b = jnp.asarray(prob.b)
-        r1 = solver.solve(b)
-        r2 = solver.solve(2.0 * b)
+    """Zero retraces across repeated solves with the stage scopes and
+    the solver's host spans on."""
+    solver = gamg.GAMGSolver(prob.A, prob.B, coarse_size=40,
+                             rtol=1e-8, maxiter=100, precision="f64")
+    b = jnp.asarray(prob.b)
+    r1 = solver.solve(b)
+    r2 = solver.solve(2.0 * b)
     assert bool(r1.converged) and bool(r2.converged)
     assert solver._solve._cache_size() == 1
     assert solver._recompute._cache_size() == 1
@@ -370,18 +412,67 @@ def test_jsonl_export_parses():
     assert hdoc["count"] == 1 and hdoc["buckets"]["1.0"] == 1
 
 
-def test_rank0_span_records_when_enabled():
-    reg = MetricsRegistry()
-    with obs_trace.use("spans"):
-        with obs_trace.rank0_span("dist/solve", registry=reg) as stop:
-            out = stop(jnp.ones(4).sum())
-    assert int(out) == 4
-    assert reg.get("dist/solve/seconds").snapshot()["count"] == 1
-    # off mode: same code path, nothing recorded
-    reg2 = MetricsRegistry()
-    with obs_trace.rank0_span("dist/solve", registry=reg2) as stop:
-        stop(jnp.ones(4).sum())
-    assert reg2.get("dist/solve/seconds") is None
+def test_host_span_records_parent_count_and_compile():
+    obs_trace.reset_host_spans()
+
+    def fresh(x):                 # a new function: its first call compiles
+        return jnp.cos(x) * 3.0 + 1.0
+
+    with obs_trace.host_span("outer"):
+        with obs_trace.host_span("outer/inner") as wait:
+            out = wait(jax.jit(fresh)(jnp.arange(5.0)))
+    with obs_trace.host_span("outer"):
+        pass
+    assert float(out[0]) == 4.0
+    inner, first, second = obs_trace.host_spans()
+    assert [r.name for r in (inner, first, second)] == [
+        "outer/inner", "outer", "outer"]
+    assert inner.parent == "outer" and first.parent is None
+    assert (inner.count, first.count, second.count) == (1, 1, 2)
+    assert first.start <= inner.start <= inner.end <= first.end
+    # the compile fell inside both open spans, and inside neither later
+    assert inner.compile_s > 0.0
+    assert first.compile_s == pytest.approx(inner.compile_s)
+    assert second.compile_s == 0.0
+    # a span that raises is not recorded
+    with pytest.raises(RuntimeError):
+        with obs_trace.host_span("outer"):
+            raise RuntimeError("boom")
+    assert len(obs_trace.host_spans()) == 3
+    obs_trace.reset_host_spans()
+    assert obs_trace.host_spans() == []
+
+
+def test_host_span_adds_no_name_stack_entry():
+    """A program first traced inside a host span carries no trace of the
+    call site (the same name as a stage scope inside it does)."""
+    x = jnp.ones(4)
+    with obs_trace.host_span("caller_site"):
+        text = _compiled_text(jax.jit(lambda v: jnp.sin(v) * 2.0), x)
+    assert "caller_site" not in text and "repro/" not in text
+
+    def staged(v):
+        with obs_trace.scope("caller_site"):
+            return jnp.sin(v) * 2.0
+
+    assert "caller_site" in _compiled_text(jax.jit(staged), x)
+
+
+def test_solver_host_spans_cover_setup_phases(prob):
+    obs_trace.reset_host_spans()
+    solver = gamg.GAMGSolver(prob.A, prob.B, coarse_size=40,
+                             rtol=1e-8, maxiter=100, precision="f64")
+    solver.solve(jnp.asarray(prob.b))
+    recs = {r.name: r for r in obs_trace.host_spans()}
+    setup = recs["setup"]
+    for phase in ("strength", "aggregate", "tentative", "prolongator",
+                  "ptap_symbolic", "ptap_numeric", "ell"):
+        rec = recs[f"setup/level0/{phase}"]
+        assert rec.parent == "setup"
+        assert setup.start <= rec.start <= rec.end <= setup.end
+    assert recs["setup/first_recompute"].parent == "setup"
+    assert setup.compile_s > 0.0
+    assert recs["solve"].parent is None and recs["solve"].count >= 1
 
 
 def test_default_registry_reset():
