@@ -9,7 +9,10 @@ Two execution paths:
 
 * ``spmv_ref`` — pure-jnp oracle over the ELL layout (always available).
 * ``spmv`` — dispatches to the Pallas TPU kernel (``repro.kernels.block_spmv``)
-  when ``use_kernel=True`` (validated in interpret mode on CPU), else the ref.
+  where it compiles natively (TPU, f32/bf16 payload) or when
+  ``use_kernel=True`` (validated in interpret mode on CPU), else the ref.
+  ``apply_ell`` sends every single-vector operator apply of the solve path
+  through it.
 """
 from __future__ import annotations
 
@@ -72,8 +75,11 @@ def block_matvec(mats: Array, v: Array) -> Array:
 def spmv_ell(ell: BlockELL, x: Array) -> Array:
     """y = A @ x on the padded ELL layout.  x: (nbc*bc,) -> y: (nbr*br,).
 
-    Padded slots point at column 0 with exactly-zero data blocks, so they
-    contribute nothing."""
+    The XLA reference of a single-vector apply.  Solve-path callers go
+    through ``apply_ell``, where single vectors and panels dispatch by the
+    same rule: this runs where the compiled kernel does not (CPU and GPU,
+    and f64 payloads on TPU).  Padded slots point at column 0 with
+    exactly-zero data blocks, so they contribute nothing."""
     with obs_trace.scope("spmv_ell"):
         xb = x.reshape(ell.nbc, ell.bc)
         return ell_contract(ell.data, xb, ell.indices).reshape(
@@ -107,8 +113,11 @@ def apply_ell_t(ell: BlockELL, pt: EllTransposePlan, x: Array) -> Array:
     duplicate is gone from the hierarchy.  Padded plan slots point at slot
     0 (a real block) and are zeroed by the mask.  The blocks are contracted
     transposed, which is exactly the stored-``r_ell`` apply's operand, in
-    the same order.  Panel-polymorphic like ``apply_ell``: ``x`` is
-    ``(nbr*br,)`` or ``(nbr*br, k)``.
+    the same order, so the two restrictions are bitwise equal on the
+    reference path only: on TPU ``apply_ell`` sends a stored f32 ``r_ell``
+    to the Pallas kernels, which this always-XLA contraction does not
+    follow.  Panel-polymorphic like ``apply_ell``: ``x`` is ``(nbr*br,)``
+    or ``(nbr*br, k)``.
     """
     with obs_trace.scope("apply_ell_t"):
         nbr, kmax, br, bc = ell.data.shape
@@ -127,18 +136,20 @@ def apply_ell_t(ell: BlockELL, pt: EllTransposePlan, x: Array) -> Array:
 
 
 def apply_ell(ell: BlockELL, x: Array) -> Array:
-    """Shape-polymorphic ELL apply: (n,) -> spmv_ell, (n, k) -> panel SpMM.
+    """Shape-polymorphic ELL apply: (n,) -> ``spmv``, (n, k) -> ``spmm``.
 
     The V-cycle and both Krylov paths route every operator application
     through this, so the whole solve hierarchy accepts column panels
-    without duplicating the recursion.  The panel branch resolves the
-    backend SpMM path (``repro.kernels.backend.resolve_spmm_path``), so
-    the Pallas ``block_spmm`` kernel engages inside the jitted solves on
-    TPU.  Resolution happens at *trace* time: like the cached
-    ``backend()`` probe, ``REPRO_SPMM_PATH`` must be set before the
-    first solve trace to affect a jitted hot path.
+    without duplicating the recursion.  Single vectors and panels dispatch
+    by the same rule, the backend and the payload dtype
+    (``repro.kernels.backend.kernels_by_default``): the Pallas
+    ``block_spmv`` / ``block_spmm`` kernels, with the ELL's own window
+    plan, inside the jitted solves on TPU for f32/bf16 payloads;
+    ``spmv_ell`` / ``spmm_ell`` everywhere else (so CPU and the ``f64``
+    policy trace exactly the XLA reference).  Resolution happens at
+    *trace* time; ``REPRO_SPMM_PATH`` forces the panel branch only.
     """
-    return spmv_ell(ell, x) if x.ndim == 1 else spmm(ell, x)
+    return spmv(ell, x) if x.ndim == 1 else spmm(ell, x)
 
 
 def spmv_bcsr_ref(A: BlockCSR, x: Array) -> Array:

@@ -11,9 +11,10 @@ import numpy as np
 import pytest
 
 import repro.core  # noqa: F401  (x64 on)
+import jax
 import jax.numpy as jnp
 
-from repro.core.spmv import spmm, spmm_ell, spmv, spmv_ell
+from repro.core.spmv import apply_ell, spmm, spmm_ell, spmv, spmv_ell
 from repro.core.spgemm import spgemm_symbolic, spgemm_numeric
 from repro.kernels.block_spmv.block_spmv import block_spmv_ell
 from repro.kernels.block_spmv.ref import block_spmv_ell_ref
@@ -88,6 +89,57 @@ def test_block_spmv_end_to_end_matches_core():
     got = spmv(A, x, use_kernel=True, interpret=True)
     want = spmv_ell(A.to_ell(), x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-12)
+
+
+# (nbr, nbc, br, bc, density) of small ELLPlan-built operators shaped like
+# the V-cycle's single-vector SpMVs: a fine-level operator (3x3 square), a
+# fine prolongator (3x6 rectangular) and a dense coarse operator (6x6,
+# kmax in the hundreds)
+VCYCLE_ELLS = {"A0-3x3": (40, 40, 3, 3, 0.2),
+               "P0-3x6": (40, 9, 3, 6, 0.3),
+               "A2-6x6-wide": (24, 300, 6, 6, 0.9)}
+
+
+def _vcycle_ell(name, dtype):
+    nbr, nbc, br, bc, density = VCYCLE_ELLS[name]
+    A = random_bcsr(np.random.default_rng(11), nbr, nbc, br, bc, density,
+                    dtype=dtype)
+    ell = A.ell_plan().build(A.data)
+    return ell, jnp.asarray(np.random.default_rng(12).standard_normal(
+        nbc * bc).astype(dtype))
+
+
+@pytest.mark.parametrize("name", sorted(VCYCLE_ELLS))
+def test_spmv_kernel_matches_reference_at_vcycle_shapes(name):
+    """The compiled-path kernel against the XLA reference, on ELLs built
+    with their window plan as the hierarchy builds them."""
+    ell, x = _vcycle_ell(name, np.float32)
+    if name == "A2-6x6-wide":
+        assert ell.kmax >= 200
+    got = spmv(ell, x, use_kernel=True, interpret=True)
+    want = spmv_ell(ell, x)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(np.float32))
+
+
+@pytest.mark.parametrize("backend_name,dtype,kernel",
+                         [("tpu", np.float32, True),
+                          ("tpu", np.float64, False),
+                          ("cpu", np.float32, False)],
+                         ids=["tpu-f32", "tpu-f64", "cpu-f32"])
+@pytest.mark.parametrize("name", sorted(VCYCLE_ELLS))
+def test_apply_ell_single_vector_dispatch(monkeypatch, name, backend_name,
+                                          dtype, kernel):
+    """A single vector dispatches by the panels' rule: the Pallas kernel
+    on TPU for an f32 payload, else exactly the ``spmv_ell`` trace."""
+    monkeypatch.setenv("REPRO_BACKEND", backend_name)
+    monkeypatch.setenv("REPRO_TUNE", "off")
+    ell, x = _vcycle_ell(name, dtype)
+    jaxpr = str(jax.make_jaxpr(lambda v: apply_ell(ell, v))(x))
+    assert ("pallas_call" in jaxpr) == kernel
+    if not kernel:
+        assert jaxpr == str(jax.make_jaxpr(lambda v: spmv_ell(ell, v))(x))
 
 
 @pytest.mark.parametrize("dtype,accum", DTYPES, ids=DTYPE_IDS)

@@ -12,6 +12,7 @@ overflow VMEM.  Nothing runs, so they say nothing about results or times.
 The topology is described inside a fixture, never at import, so that only
 the worker given this file loads the TPU library.
 """
+import re
 import types
 
 import pytest
@@ -124,6 +125,10 @@ def _pair_gemm(nslots, kmax, br, bk, bc):
 KERNELS = {
     "block_spmv-A0-3x3": lambda: _spmv(NBR0, KMAX0, 3, 3, NBR0),
     "block_spmv-P0-3x6": lambda: _spmv(NBR0, 8, 3, 6, 1331),
+    "block_spmv-A1-6x6": lambda: _spmv(1331, 45, 6, 6, 1331),
+    "block_spmv-A2-6x6": lambda: _spmv(836, 490, 6, 6, 836),
+    "block_spmv-P1-6x6": lambda: _spmv(1331, 45, 6, 6, 836),
+    "block_spmv-P2-6x6": lambda: _spmv(836, 19, 6, 6, 19),
     "block_spmm-A0-3x3-k8": lambda: _spmm(NBR0, KMAX0, 3, 8),
     "block_spmm-A1-6x6-k8": lambda: _spmm(1331, 45, 6, 8),
     "block_spmm-A2-6x6-k16": lambda: _spmm(836, 490, 6, 16),
@@ -177,9 +182,17 @@ def _m32_hierarchy(chip) -> Hierarchy:
                                      chip))
 
 
+def _kernel_scopes(compiled) -> list:
+    """``op_name`` of every compiled Pallas call in the program text."""
+    return re.findall(r'custom_call_target="tpu_custom_call".*?'
+                      r'op_name="([^"]*)"', compiled.as_text())
+
+
 def test_whole_solve_compiles_for_v5e(chip, tpu_dispatch):
     """One jitted AMG-PCG solve at m=32 under the f32 policy: the compiled
-    program holds the fused smoother kernels and fits one chip's HBM."""
+    program holds the fused smoother kernels, runs every level's residual
+    and prolongation on the ``block_spmv`` kernel, and fits one chip's
+    HBM."""
     from repro.core import gamg
     setupd = types.SimpleNamespace(
         smoother="chebyshev", degree=2,
@@ -190,6 +203,11 @@ def test_whole_solve_compiles_for_v5e(chip, tpu_dispatch):
     # Chebyshev degree 2: two fused steps per smoothing, pre and post,
     # on each of the three levels
     assert _kernel_calls(compiled) >= 2 * len(LEVELS)
+    scopes = _kernel_scopes(compiled)
+    for li in range(len(LEVELS)):
+        for stage in ("residual", "prolong"):
+            site = f"vcycle/level{li}/{stage}/kernels/block_spmv/"
+            assert any(site in name for name in scopes), (site, scopes)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
